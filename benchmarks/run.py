@@ -146,6 +146,10 @@ def main() -> None:
         list_gates()
         return
 
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (fig09_training_curve, fig10_dgro_vs_ga,
                             fig11_ring_selection, fig12_ring_ablation,
                             fig13_kring_compare, fig14_parallel,

@@ -12,7 +12,8 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np                      # noqa: E402
 import jax                              # noqa: E402
 import jax.numpy as jnp                 # noqa: E402
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
+from repro.compat import make_mesh  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_arch      # noqa: E402

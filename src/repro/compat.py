@@ -1,107 +1,23 @@
-"""jax API-version compatibility shims.
+"""``make_mesh``: ``jax.make_mesh`` with Auto axis types.
 
-The codebase targets the modern jax surface (top-level ``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``,
-``check_vma=``); older pins (e.g. 0.4.x, where shard_map lives in
-``jax.experimental`` and meshes have no axis types) lack parts of it.  All
-imports of these symbols go through this module so the rest of the tree can
-be written against one API regardless of the installed jax.
+``jax.make_mesh`` gives Explicit axis types by default; the meshes in this
+repo rely on Auto sharding propagation (``jax.jit`` shardings and
+``jax.shard_map``), so they are all built here.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["shard_map", "make_mesh", "mesh_from_devices", "abstract_mesh",
-           "auto_axis_types", "axis_size", "named_sharding"]
-
-try:  # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_HAS_CHECK_VMA = "check_vma" in inspect.signature(_shard_map_impl).parameters
-_MAKE_MESH = getattr(jax, "make_mesh", None)       # absent before jax 0.4.35
-_MAKE_MESH_AXIS_TYPES = (
-    _MAKE_MESH is not None
-    and "axis_types" in inspect.signature(_MAKE_MESH).parameters)
-
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-
-def auto_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n`` on jax versions that have axis types."""
-    if AxisType is None:
-        return None
-    return (AxisType.Auto,) * n_axes
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` with the replication-check kwarg name normalized
-    (``check_vma`` on modern jax, ``check_rep`` on 0.4.x)."""
-    kw = {}
-    if check_vma is not None:
-        kw["check_vma" if _HAS_CHECK_VMA else "check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported; on jax
-    versions predating make_mesh, a plain device-grid Mesh."""
-    shape, names = tuple(axis_shapes), tuple(axis_names)
-    if _MAKE_MESH is None:
-        import numpy as np
-
-        devs = np.asarray(devices if devices is not None else jax.devices())
-        return Mesh(devs[:int(np.prod(shape))].reshape(shape), names)
-    kw = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if _MAKE_MESH_AXIS_TYPES:
-        kw["axis_types"] = auto_axis_types(len(names))
-    return _MAKE_MESH(shape, names, **kw)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, from inside shard_map.
-
-    ``jax.lax.axis_size`` on modern jax; on 0.4.x ``jax.core.axis_frame``
-    resolves the name in the ambient axis env (returning the size int).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    frame = jax.core.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
-
-
-def named_sharding(mesh: Mesh, *spec):
-    """``NamedSharding(mesh, PartitionSpec(*spec))`` — placing a host batch
-    explicitly before a shard_map call avoids the implicit broadcast-then-
-    reshard transfer some jax versions emit for unsharded inputs."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return NamedSharding(mesh, PartitionSpec(*spec))
-
-
-def abstract_mesh(axis_shapes: Sequence[int],
-                  axis_names: Sequence[str]) -> AbstractMesh:
-    """``AbstractMesh(sizes, names)`` (modern) vs ``AbstractMesh(pairs)``
-    (0.4.x)."""
-    try:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
-
-
-def mesh_from_devices(device_array, axis_names: Sequence[str]) -> Mesh:
-    """``Mesh(devices, axes)`` with Auto axis types where supported."""
-    try:
-        return Mesh(device_array, axis_names,
-                    axis_types=auto_axis_types(len(tuple(axis_names))))
-    except TypeError:
-        return Mesh(device_array, axis_names)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)

@@ -689,9 +689,7 @@ def _sharded_diameter_fn(mesh, axis: str, use_kernel: bool, method: str,
                          symmetric: bool, dtype: str, tile: Optional[int]):
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda a: batched_diameter(a, use_kernel=use_kernel, method=method,
                                    symmetric=symmetric, dtype=dtype,
                                    tile=tile),
@@ -707,12 +705,12 @@ def diameters_sharded(adjs, *, mesh=None, axis: str = "batch",
 
     Follows the ``parallel_ring_shmap`` pattern: pad B to a multiple of
     the mesh axis, place the stack with a ``NamedSharding``, and run
-    ``batched_diameter`` per shard under ``compat.shard_map`` (no
+    ``batched_diameter`` per shard under ``jax.shard_map`` (no
     collectives — each device scores its own sub-batch).  With no mesh, a
     1D ``launch.mesh.make_eval_mesh`` over all local devices is built; on
     a single device this degrades to the streaming facade.
     """
-    from repro import compat
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     adjs = np.asarray(adjs, np.float32)
     assert adjs.ndim == 3 and adjs.shape[1] == adjs.shape[2], adjs.shape
@@ -740,7 +738,7 @@ def diameters_sharded(adjs, *, mesh=None, axis: str = "batch",
                               axis=0)
     fn = _sharded_diameter_fn(mesh, axis, use_kernel, method, symmetric,
                               compute_dtype, tile)
-    placed = jax.device_put(adjs, compat.named_sharding(mesh, axis))
+    placed = jax.device_put(adjs, NamedSharding(mesh, P(axis)))
     t0 = time.perf_counter()
     out = np.asarray(fn(placed))
     per = adjs.shape[0] // k
@@ -760,8 +758,6 @@ def diameters_sharded(adjs, *, mesh=None, axis: str = "batch",
 def _rowshard_fn(mesh, axis: str, npad: int, n_iters: int):
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     def local(loc):
         def squaring(_, loc):
             full = jax.lax.all_gather(loc, axis, axis=0, tiled=True)
@@ -775,8 +771,8 @@ def _rowshard_fn(mesh, axis: str, npad: int, n_iters: int):
 
         return jax.lax.fori_loop(0, n_iters, squaring, loc)
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=(P(axis, None),),
-                          out_specs=P(axis, None))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(axis, None),),
+                       out_specs=P(axis, None))
     return jax.jit(fn)
 
 
@@ -805,10 +801,10 @@ def apsp_rowshard(adj: np.ndarray, *, mesh=None,
         padded[:n, :n] = adj
         adj = padded
     n_iters = max(1, int(np.ceil(np.log2(max(npad - 1, 2)))))
-    from repro import compat
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     fn = _rowshard_fn(mesh, axis, npad, n_iters)
-    placed = jax.device_put(adj, compat.named_sharding(mesh, axis))
+    placed = jax.device_put(adj, NamedSharding(mesh, P(axis)))
     t0 = time.perf_counter()
     out = np.asarray(fn(placed))
     item = 4

@@ -23,7 +23,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-INF = jnp.float32(1e9)  # finite "infinity": avoids inf-inf NaN in min-plus
+# finite "infinity": avoids inf-inf NaN in min-plus.  A host scalar, so
+# importing this module creates no device array (and takes no chip).
+INF = np.float32(1e9)
 
 __all__ = [
     "INF",

@@ -50,7 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from . import batcheval
 from .construction import nearest_ring, nearest_ring_jax, nearest_rings_batched

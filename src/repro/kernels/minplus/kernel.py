@@ -1,23 +1,24 @@
-"""Pallas TPU kernel: tiled min-plus (tropical) matrix product.
+"""Pallas TPU kernels: tiled min-plus (tropical) products and blocked FW.
 
 C[i, j] = min_k A[i, k] + B[k, j]
 
-This is the inner step of the min-plus-squaring APSP used by
-``repro.core.diameter`` — the paper's diameter computation is the hot spot of
-both the Q-learning reward loop and the GA baseline.  Min-plus has no
-multiply-accumulate, so it maps to the VPU (not the MXU); the tiling is
-therefore chosen for VMEM residency and 8x128 vector-lane alignment rather
-than for MXU 128x128 systolic shape:
+This is the inner step of the min-plus APSP used by ``repro.core.diameter``
+and ``repro.core.batcheval`` — the paper's diameter computation is the hot
+spot of both the Q-learning reward loop and the GA baseline.  Min-plus has
+no multiply-accumulate, so it maps to the VPU (not the MXU).
 
-  * grid (M/bm, N/bn, K/bk), K innermost so the output block stays resident
-    in VMEM across the K panels (revisiting rule on TPU: last grid dim is
-    sequential minor-most).
-  * each (bm, bk) x (bk, bn) panel is reduced in CHUNK=8 slabs: a
-    (bm, 8, bn) broadcast-add + min keeps the temporary under 0.5 MiB
-    (bm=bn=128) while amortizing loop overhead over full 8x128 vregs.
-  * VMEM per step: A tile 64 KiB + B tile 64 KiB + C tile 64 KiB fp32
-    (+ double buffering) — far below the ~16 MiB/core budget, leaving room
-    for the pipeline to prefetch the next K panel.
+Every product body is ``_minplus_rows``: rank-1 updates
+``acc = min(acc, A[:, k] + B[k, :])`` over a STATIC loop on k, with the
+accumulator walked ``_row_block`` rows at a time so the rows being reduced
+stay in vregs.  The column ``A[:, k]`` is a static lane slice broadcast
+across lanes and the row ``B[k, :]`` a static sublane load broadcast across
+sublanes.  Nothing is sliced dynamically except whole row blocks (aligned
+sublane offsets): Mosaic lowers neither a ``dynamic_slice`` of a value nor
+a dynamic slice of a ref along the lane dimension.
+
+Min over floats is exact and each candidate ``A[i, k] + B[k, j]`` is one
+rounded add, so any regrouping of the same candidate set — this kernel's
+row blocks, the oracles' broadcasts — gives identical bits.
 """
 from __future__ import annotations
 
@@ -28,47 +29,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 INF = 1e9
-_CHUNK = 8
 
 
-def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int):
-    k_step = pl.program_id(2)
+def _row_block(m: int) -> int:
+    """Accumulator rows per pass: 32 where they divide M (whole vregs in
+    f32 and bf16 alike), else the largest of 16 and 8 that does."""
+    for rb in (32, 16, 8):
+        if m % rb == 0:
+            return rb
+    return m
 
-    @pl.when(k_step == 0)
+
+def _minplus_rows(c_ref, a_ref, b_ref, o_ref):
+    """``o = min(c, a ⊗ b)`` for refs a (M, K), b (K, N) and c, o (M, N).
+
+    ``c`` and ``o`` may be the same ref (accumulate in place); ``b`` is
+    never written, so it is the frozen operand even when ``c`` aliases it
+    by value (the panel updates pass one tile as both).
+    """
+    m, k = a_ref.shape
+    rb = _row_block(m)
+
+    def rows(r, carry):
+        i = pl.multiple_of(r * rb, rb)
+        acc = c_ref[pl.ds(i, rb), :]
+        a = a_ref[pl.ds(i, rb), :]
+        for kk in range(k):
+            acc = jnp.minimum(acc, a[:, kk:kk + 1] + b_ref[kk:kk + 1, :])
+        o_ref[pl.ds(i, rb), :] = acc
+        return carry
+
+    jax.lax.fori_loop(0, m // rb, rows, 0)
+
+
+def _minplus_kernel(a_ref, b_ref, o_ref):
+    """One (bm, bn) output block; the K panels are the last grid axis, so
+    the block stays resident in VMEM while they accumulate into it."""
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, INF)
 
-    a = a_ref[...]  # (bm, bk)
-    b = b_ref[...]  # (bk, bn)
-
-    def body(c, acc):
-        a_slab = jax.lax.dynamic_slice_in_dim(a, c * _CHUNK, _CHUNK, axis=1)
-        b_slab = jax.lax.dynamic_slice_in_dim(b, c * _CHUNK, _CHUNK, axis=0)
-        cand = a_slab[:, :, None] + b_slab[None, :, :]       # (bm, CHUNK, bn)
-        return jnp.minimum(acc, jnp.min(cand, axis=1))
-
-    o_ref[...] = jax.lax.fori_loop(0, bk // _CHUNK, body, o_ref[...])
-
-
-def _minplus_kernel_batched(a_ref, b_ref, o_ref, *, bk: int):
-    """Batched variant: leading grid axis walks the batch; block shapes carry
-    a unit batch dim that is squeezed before the slab reduction."""
-    k_step = pl.program_id(3)
-
-    @pl.when(k_step == 0)
-    def _init():
-        o_ref[...] = jnp.full_like(o_ref, INF)
-
-    a = a_ref[0]  # (bm, bk)
-    b = b_ref[0]  # (bk, bn)
-
-    def body(c, acc):
-        a_slab = jax.lax.dynamic_slice_in_dim(a, c * _CHUNK, _CHUNK, axis=1)
-        b_slab = jax.lax.dynamic_slice_in_dim(b, c * _CHUNK, _CHUNK, axis=0)
-        cand = a_slab[:, :, None] + b_slab[None, :, :]       # (bm, CHUNK, bn)
-        return jnp.minimum(acc, jnp.min(cand, axis=1))
-
-    o_ref[0] = jax.lax.fori_loop(0, bk // _CHUNK, body, o_ref[0])
+    _minplus_rows(o_ref, a_ref, b_ref, o_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -82,29 +83,40 @@ def minplus_pallas_batched(
 ) -> jnp.ndarray:
     """Batched tiled min-plus: ``(B, M, K) x (B, K, N) -> (B, M, N)``.
 
-    The batch axis is the OUTERMOST grid dimension, so each batch element's
-    output tiles are finished before the next element starts and the
-    per-step VMEM footprint is identical to the unbatched kernel (the
-    batch never touches VMEM as a whole).
+    Grid (B, M/bm, N/bn, K/bk): the batch axis is the OUTERMOST grid
+    dimension, so each batch element's output tiles are finished before the
+    next element starts and the per-step VMEM footprint is that of one
+    (bm, bk) x (bk, bn) product (the batch never touches VMEM as a whole).
+    K is innermost (the TPU revisiting rule: the last grid axis is the
+    sequential minor-most one).  Inputs must be fp32 with dims divisible by
+    the blocks (``ops`` pads); a block must be a multiple of 128 or span
+    its whole dimension for Mosaic to accept it.
     """
     bsz, m, k = a.shape
     bsz2, k2, n = b.shape
     assert bsz == bsz2 and k == k2, (a.shape, b.shape)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (a.shape, b.shape, bm, bn, bk)
-    assert bk % _CHUNK == 0, bk
 
-    grid = (bsz, m // bm, n // bn, k // bk)
     return pl.pallas_call(
-        functools.partial(_minplus_kernel_batched, bk=bk),
-        grid=grid,
+        _minplus_kernel,
+        grid=(bsz, m // bm, n // bn, k // bk),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda bb, i, j, kk: (bb, i, kk)),
-            pl.BlockSpec((1, bk, bn), lambda bb, i, j, kk: (bb, kk, j)),
+            pl.BlockSpec((None, bm, bk), lambda bb, i, j, kk: (bb, i, kk)),
+            pl.BlockSpec((None, bk, bn), lambda bb, i, j, kk: (bb, kk, j)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
+        out_specs=pl.BlockSpec((None, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, m, n), jnp.float32),
         interpret=interpret,
     )(a, b)
+
+
+def minplus_pallas(a: jnp.ndarray, b: jnp.ndarray, bm: int = 128,
+                   bn: int = 128, bk: int = 128,
+                   interpret: bool = False) -> jnp.ndarray:
+    """Tiled min-plus product ``(M, K) x (K, N)``: the batched kernel on a
+    unit batch."""
+    return minplus_pallas_batched(a[None], b[None], bm=bm, bn=bn, bk=bk,
+                                  interpret=interpret)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -112,9 +124,7 @@ def minplus_pallas_batched(
 # ---------------------------------------------------------------------------
 #
 # Per diagonal block k, three kernels over the same (T, T) block grid as
-# ``ref.apsp_tiled_ref`` (which is the bit-exact CPU twin — min over floats
-# is exact, so the 8-slab reductions here regroup the rank-1 candidate sets
-# of the ref without changing a single bit):
+# ``ref.apsp_tiled_ref`` (which is the bit-exact jnp twin):
 #
 #   1. ``_fw_diag_kernel``    — close the diagonal tile in VMEM (rank-1 FW,
 #      sequential over T pivots: each pivot depends on the previous).
@@ -124,47 +134,45 @@ def minplus_pallas_batched(
 #      (N/T, N/T) block grid; each grid step reads one stationary output
 #      tile plus one panel tile from each operand (K = T, single panel).
 #
-# VMEM per step at T=256 fp32: 3-4 tiles of 256 KiB + the (T, 8, T) slab
-# temporary — ~1.3 MiB, far under the ~16 MiB/core budget, so the pipeline
-# can double-buffer the next tile while the VPU reduces the current one.
+# VMEM per step at T=256 fp32: four tiles of 256 KiB, double-buffered —
+# ~2 MiB, far under the ~16 MiB/core budget.
 
 
 def _fw_diag_kernel(d_ref, o_ref):
-    """Rank-1 Floyd-Warshall closure of one (T, T) tile, fully in VMEM."""
-    def body(k, d):
-        row = jax.lax.dynamic_slice_in_dim(d, k, 1, axis=0)     # (1, T)
-        col = jax.lax.dynamic_slice_in_dim(d, k, 1, axis=1)     # (T, 1)
-        return jnp.minimum(d, col + row)
+    """Rank-1 Floyd-Warshall closure of one (T, T) tile, in place in VMEM.
 
-    o_ref[...] = jax.lax.fori_loop(0, d_ref.shape[0], body, d_ref[...])
+    The pivot's row and column are picked by masked min-reductions (a
+    dynamic pivot index may not slice the lane dimension); min over the
+    pivot entry and +inf is that entry, bit for bit.
+    """
+    t = d_ref.shape[0]
+    o_ref[...] = d_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
 
+    def body(k, carry):
+        d = o_ref[...]
+        col = jnp.min(jnp.where(lane == k, d, jnp.inf), axis=1, keepdims=True)
+        row = jnp.min(jnp.where(sub == k, d, jnp.inf), axis=0, keepdims=True)
+        o_ref[...] = jnp.minimum(d, col + row)
+        return carry
 
-def _slab_minplus(acc, a, b):
-    """min(acc, a ⊗ b) by CHUNK-slab reduction; a is (M, T), b is (T, N)."""
-    def body(c, acc):
-        a_slab = jax.lax.dynamic_slice_in_dim(a, c * _CHUNK, _CHUNK, axis=1)
-        b_slab = jax.lax.dynamic_slice_in_dim(b, c * _CHUNK, _CHUNK, axis=0)
-        cand = a_slab[:, :, None] + b_slab[None, :, :]      # (M, CHUNK, N)
-        return jnp.minimum(acc, jnp.min(cand, axis=1))
-
-    return jax.lax.fori_loop(0, a.shape[1] // _CHUNK, body, acc)
+    jax.lax.fori_loop(0, t, body, 0)
 
 
 def _panel_left_kernel(diag_ref, p_ref, o_ref):
     """One (T, T) block of the row panel: o = min(p, diag ⊗ p)."""
-    p = p_ref[...]
-    o_ref[...] = _slab_minplus(p, diag_ref[...], p)
+    _minplus_rows(p_ref, diag_ref, p_ref, o_ref)
 
 
 def _panel_right_kernel(p_ref, diag_ref, o_ref):
     """One (T, T) block of the column panel: o = min(p, p ⊗ diag)."""
-    p = p_ref[...]
-    o_ref[...] = _slab_minplus(p, p, diag_ref[...])
+    _minplus_rows(p_ref, p_ref, diag_ref, o_ref)
 
 
 def _outer_kernel(d_ref, colp_ref, rowp_ref, o_ref):
     """One (T, T) output tile: o = min(d, colp_tile ⊗ rowp_tile)."""
-    o_ref[...] = _slab_minplus(d_ref[...], colp_ref[...], rowp_ref[...])
+    _minplus_rows(d_ref, colp_ref, rowp_ref, o_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -173,14 +181,13 @@ def apsp_tiled_pallas(d: jnp.ndarray, tile: int = 256,
     """Blocked Floyd-Warshall APSP over a (N/T, N/T) Pallas block grid.
 
     ``d`` is one (N, N) adjacency (0 diag, INF non-edges) with N divisible
-    by ``tile`` and ``tile`` divisible by 8 (``ops.apsp_tiled`` pads).
-    Keeps dtype (fp32 or bf16).  Bit-identical to ``ref.apsp_tiled_ref``
-    on the same padded input — the module docstring above explains why.
+    by ``tile`` (``ops.apsp_tiled`` pads; ``ops.default_tile`` picks tiles
+    the chip accepts).  Keeps dtype (fp32 or bf16).  Bit-identical to
+    ``ref.apsp_tiled_ref`` on the same padded input.
     """
     n = d.shape[0]
     assert d.ndim == 2 and d.shape[1] == n, d.shape
     assert n % tile == 0, (n, tile)
-    assert tile % _CHUNK == 0, tile
     nb = n // tile
     dt = d.dtype
 
@@ -226,34 +233,3 @@ def apsp_tiled_pallas(d: jnp.ndarray, tile: int = 256,
         return outer(d, colp, rowp)
 
     return jax.lax.fori_loop(0, nb, kblock, d)
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def minplus_pallas(
-    a: jnp.ndarray,
-    b: jnp.ndarray,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Tiled min-plus product.  Inputs must be fp32 with dims divisible by
-    the block sizes (``ops.minplus`` handles padding)."""
-    m, k = a.shape
-    k2, n = b.shape
-    assert k == k2, (a.shape, b.shape)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0, (a.shape, b.shape, bm, bn, bk)
-    assert bk % _CHUNK == 0, bk
-
-    grid = (m // bm, n // bn, k // bk)
-    return pl.pallas_call(
-        functools.partial(_minplus_kernel, bk=bk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(a, b)

@@ -17,7 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import (INF, _CHUNK, apsp_tiled_pallas, minplus_pallas,
+from .kernel import (INF, apsp_tiled_pallas, minplus_pallas,
                      minplus_pallas_batched)
 from .ref import apsp_tiled_ref, minplus_batched_ref, minplus_ref
 
@@ -27,18 +27,25 @@ def _ceil_to(x: int, mult: int) -> int:
 
 
 def _auto_block(*dims: int) -> int:
-    """Smallest 8-multiple covering the largest dim, capped at 128 (the
-    TPU lane-aligned tile; larger shapes are gridded over 128-blocks)."""
-    return min(128, _ceil_to(max(max(dims), _CHUNK), _CHUNK))
+    """Smallest 8-multiple covering the largest dim, capped at 128.  Below
+    the cap the block spans the whole padded operand, which Mosaic accepts
+    at any 8-multiple; larger shapes are gridded over lane-aligned
+    128-blocks."""
+    return min(128, _ceil_to(max(max(dims), 8), 8))
 
 
 def default_tile(n: int, cap: int = 256) -> int:
-    """Tile for the blocked-FW APSP: the smallest 8-multiple tiling N in
-    ``ceil(N / cap)`` blocks, so padding waste stays under one 8-row slab
-    per block row instead of rounding N all the way up to a cap multiple
-    (N=300 tiles as 2 x 152, not 2 x 256)."""
-    nb = max(1, -(-n // cap))
-    return _ceil_to(max(-(-n // nb), _CHUNK), _CHUNK)
+    """Tile for the blocked-FW APSP, one the chip accepts in f32 and bf16.
+
+    Up to ``cap`` nodes the whole padded matrix is one block, which Mosaic
+    takes at any multiple of 16 (bf16's sublane tile).  Past it a (T, T)
+    block of a larger array must be lane-aligned, so T is the multiple of
+    128 up to ``cap`` that pads N least, the larger on a tie (N=300 tiles
+    as 3 x 128, not 2 x 256; N=986 as 4 x 256).
+    """
+    if n <= cap:
+        return _ceil_to(max(n, 16), 16)
+    return min(range(128, cap + 1, 128), key=lambda t: (_ceil_to(n, t), -t))
 
 
 def _pad_to(x: jnp.ndarray, mult: int, fill: float) -> jnp.ndarray:

@@ -4,8 +4,8 @@
 ``kernel.apsp_tiled_pallas``: it sequences the SAME three per-k-block
 phases over the SAME (tile, tile) block grid, so CPU CI exercises the
 kernel's block logic bit-for-bit (min over floats is exact, so any
-regrouping of the same candidate set — the kernel's 8-slab reduction vs
-the rank-1 loops here — produces identical bits).
+regrouping of the same candidate set — the kernel's row-blocked rank-1
+loops vs the whole-matrix ones here — produces identical bits).
 """
 from __future__ import annotations
 

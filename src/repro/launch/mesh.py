@@ -23,8 +23,9 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.sharding import Mesh
 
-from repro.compat import make_mesh, mesh_from_devices
+from repro.compat import make_mesh
 from repro.core.construction import nearest_ring, random_ring
 from repro.core.diameter import adjacency_from_rings, diameter_scipy
 from repro.core.selection import (clustering_ratio, measure_latency_stats,
@@ -115,6 +116,6 @@ def make_production_mesh(*, multi_pod: bool = False, dgro_order: bool = False,
     grid = devices.reshape(n_dcn, n_model)
     grid = grid[order]                         # DGRO permutation of DCN axis
     dev = grid.reshape(shape)
-    mesh = mesh_from_devices(dev, axes)
+    mesh = Mesh(dev, axes)
     mesh.dgro_report = report                  # type: ignore[attr-defined]
     return mesh

@@ -24,7 +24,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ArchConfig
 from .layers import dense_init

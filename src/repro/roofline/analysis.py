@@ -116,8 +116,6 @@ class Roofline:
 
 
 def roofline_from(cost: Dict, hlo_text: str) -> Roofline:
-    if isinstance(cost, (list, tuple)):       # jax 0.4.x: list of one dict
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(hlo_text)

@@ -61,7 +61,7 @@ POLICIES = ("ring", "latency")
 
 # score assigned to non-edges / useless hops; must stay above any real
 # ``adj + D`` sum (each < INF) yet well inside float32 range
-_BLOCKED = jnp.float32(4.0) * INF
+_BLOCKED = np.float32(4.0) * INF
 _HALF_INF = float(INF) / 2
 
 

@@ -52,6 +52,7 @@ from repro import serde
 from repro.dynamics.scenarios import Event, Trace
 from repro.obs import REGISTRY, configure as configure_logging, get_logger, kv
 from repro.obs.metrics import LATENCY_BUCKETS_S
+from repro.runtime import enable_compile_cache
 
 from .reoptimizer import Reoptimizer
 from .state import ServiceState
@@ -343,6 +344,7 @@ def main(argv=None) -> None:
     # SERVING/STOPPED stdout lines below stay — they are the boot protocol
     # the smoke tools parse
     configure_logging(default="info")
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0,

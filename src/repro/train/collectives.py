@@ -19,7 +19,7 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import axis_size, shard_map
+from jax import shard_map
 
 PyTree = Any
 
@@ -33,7 +33,7 @@ def _quantize(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def _ring_allreduce_1d(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """Ring all-reduce (reduce-scatter + all-gather) of a flat fp32 vector
     with int8-compressed hops.  x must divide by the axis size."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     i = jax.lax.axis_index(axis)
     chunks = x.reshape(n, -1)
     fwd = [(j, (j + 1) % n) for j in range(n)]
@@ -76,7 +76,7 @@ def ring_allreduce(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """int8-compressed ring all-reduce — call INSIDE shard_map.  ``x`` is a
     per-shard fp32 array of identical shape on every shard; returns the sum.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     flat = x.astype(jnp.float32).reshape(-1)
     pad = (-flat.size) % n
     if pad:
@@ -97,7 +97,7 @@ def compressed_grad_allreduce(grads: PyTree, axis: str = "data",
     Returns (reduced_grads, new_error_feedback): the residual the local
     quantization dropped this step, to be added to next step's grads.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if error_fb is not None:
         grads = jax.tree.map(lambda g, e: g + e.astype(g.dtype), grads, error_fb)
 

@@ -21,7 +21,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig

@@ -29,7 +29,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
+from repro.compat import make_mesh
 from repro.train.collectives import ring_allreduce, compressed_grad_allreduce
 
 mesh = make_mesh((8,), ("data",))

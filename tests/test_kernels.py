@@ -107,15 +107,19 @@ def test_minplus_batched_adaptive_block_bit_identical():
 
 
 def test_adaptive_block_sizes():
-    """The auto block covers small operands without padding to 128 and
-    the auto tile splits non-multiple N into balanced multiple-of-8 tiles."""
+    """The auto block covers small operands without padding to 128, and
+    the auto tile is one the chip accepts: a single whole-matrix block at a
+    16-multiple up to the cap, else the 128-multiple that pads least."""
     from repro.kernels.minplus.ops import _auto_block, default_tile
     assert _auto_block(20, 33) == 40       # ceil(33 -> /8) is 40, not 128
     assert _auto_block(7, 5) == 8
     assert _auto_block(300, 40) == 128     # large dims still cap at 128
     assert default_tile(256) == 256
-    assert default_tile(300) == 152        # 2 tiles of 152, not 2 of 256
+    assert default_tile(100) == 112        # one block, bf16 sublane multiple
+    assert default_tile(300) == 128        # 3 x 128 pads to 384, 2 x 256 to 512
+    assert default_tile(986) == 256        # 1024 either way: fewer blocks
     assert default_tile(1024) == 256
+    assert default_tile(4096) == 256
 
 
 # --- tiled (blocked) Floyd-Warshall APSP ------------------------------------

@@ -340,6 +340,21 @@ def test_http_queries_survive_inflight_reopt():
 # the real daemon: env-injected crash + restart (subprocess)
 # ---------------------------------------------------------------------------
 
+def test_imports_initialize_no_backend():
+    """A chip belongs to one process: a parent that only imports the
+    control plane (to drive a daemon child, as ``tools/service_smoke.py``
+    does) must not have created a device array and so taken the chip."""
+    code = ("import repro.service, repro.hier, repro.routing, "
+            "repro.dynamics.scenarios, repro.overlay\n"
+            "from repro.runtime import backend_initialized\n"
+            "print('INITIALIZED', backend_initialized())")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=subproc_env(), cwd=".", timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == "INITIALIZED False", \
+        out.stdout
+
+
 def test_daemon_crash_env_and_restart_consistency(tmp_path):
     snapdir = str(tmp_path)
     base_cmd = [sys.executable, "-m", "repro.service", "--n0", "20",

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.configs import ARCHS, get_arch
 from repro.launch.shardings import (batch_specs, cache_specs, param_specs,
                                     spec_for_param, state_specs, zero_spec)
@@ -19,7 +19,7 @@ from repro.models import model as Mdl
 
 
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))

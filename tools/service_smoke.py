@@ -29,6 +29,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.dynamics.scenarios import poisson_churn  # noqa: E402
+from repro.runtime import backend_initialized  # noqa: E402
 from repro.service import ServiceClient  # noqa: E402
 
 
@@ -53,6 +54,9 @@ def main() -> None:
     assert len(events) >= min(args.events, 40), (
         f"trace only produced {len(events)} events")
 
+    # a chip belongs to one process: the daemon child needs it, so this
+    # parent must never have touched a JAX backend
+    assert not backend_initialized(), "smoke parent initialized a JAX backend"
     snapdir = tempfile.mkdtemp(prefix="dgro-service-smoke-")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.service",
