@@ -56,7 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.obs import REGISTRY, jit_phase, jit_span
+from repro.obs import REGISTRY, jit_phase, jit_span, span
 from repro.obs.tracing import SPAN_BUCKETS_S
 
 from .diameter import INF, is_edge, largest_cc_diameter
@@ -495,13 +495,29 @@ def _observe_call(method: str, key, seconds: float, ws_bytes: int) -> None:
     _APSP_WORKINGSET.set(ws_bytes)
 
 
+def _phased_call(fn, blk: np.ndarray, prefix: str) -> np.ndarray:
+    """``np.asarray(fn(blk))`` with its upload, device work and download in
+    spans ``<prefix>.to_device``, ``<prefix>.device`` and
+    ``<prefix>.to_host``.  The download waits for the result anyway, so
+    waiting for it one step earlier splits the time without a sync of its
+    own."""
+    with span(prefix + ".to_device"):
+        x = jnp.asarray(blk).block_until_ready()
+    with span(prefix + ".device"):
+        y = jax.block_until_ready(fn(x))
+    with span(prefix + ".to_host"):
+        return np.asarray(y)
+
+
 def _stream(src, b: int, n: int, fn, *, chunk: int, method: str,
             compute_dtype: str, quantize: bool, symmetric: bool,
-            use_kernel: bool, tile: Optional[int], ws_bytes: int):
+            use_kernel: bool, tile: Optional[int], ws_bytes: int,
+            span_prefix: Optional[str] = None):
     """Drive ``fn`` over fixed-size chunks of ``src``, never holding more
     than one (chunk, N, N) block on host or device.  The trailing partial
     chunk is padded by repeating its first matrix so every device call has
-    the SAME compiled shape (one trace, not one per remainder)."""
+    the SAME compiled shape (one trace, not one per remainder).  With a
+    ``span_prefix`` each chunk's phases run in spans (``_phased_call``)."""
     outs = []
     calls = 0
     max_scale = 0.0
@@ -516,7 +532,10 @@ def _stream(src, b: int, n: int, fn, *, chunk: int, method: str,
             blk = np.concatenate(
                 [blk, np.repeat(blk[:1], chunk - (hi - lo), axis=0)], axis=0)
         t0 = time.perf_counter()
-        res = np.asarray(fn(jnp.asarray(blk)))
+        if span_prefix is None:
+            res = np.asarray(fn(jnp.asarray(blk)))
+        else:
+            res = _phased_call(fn, blk, span_prefix)
         _observe_call(method,
                       (blk.shape[0], n, use_kernel, method, symmetric,
                        compute_dtype, tile),
@@ -625,7 +644,8 @@ def _verify_quantized(src, b: int, out: np.ndarray, rtol: Optional[float],
 def apsp_matrices(adjs, *, use_kernel: bool = False,
                   method: str | None = None, symmetric: bool = True,
                   chunk: int | None = None, dtype: str | None = None,
-                  tile: int | None = None) -> np.ndarray:
+                  tile: int | None = None,
+                  span_prefix: str | None = None) -> np.ndarray:
     """Full (B, N, N) float32 APSP distance matrices, streamed per chunk.
 
     The matrix-returning sibling of ``diameters`` for consumers that need
@@ -634,7 +654,9 @@ def apsp_matrices(adjs, *, use_kernel: bool = False,
     instrumentation, with the result re-widened to float32 on host.  The
     HOST output is dense (the caller asked for it); only device memory is
     bounded.  No probe-verification here — reduced precision is the
-    caller's explicit contract for distances.
+    caller's explicit contract for distances.  ``span_prefix`` names the
+    spans of each chunk's upload, device work and download (the churn
+    engine's rebuild passes ``"rebuild"``).
     """
     src = _as_source(adjs)
     b, n = len(src), src.n
@@ -662,7 +684,8 @@ def apsp_matrices(adjs, *, use_kernel: bool = False,
     outs, calls, max_scale = _stream(
         src, b, n, fn, chunk=chunk, method=method,
         compute_dtype=compute_dtype, quantize=dtype == "int16",
-        symmetric=symmetric, use_kernel=use_kernel, tile=tile, ws_bytes=ws)
+        symmetric=symmetric, use_kernel=use_kernel, tile=tile, ws_bytes=ws,
+        span_prefix=span_prefix)
     _report.data = {"b": b, "n": n, "method": method, "dtype": dtype,
                     "chunk": chunk, "tile": tile, "workingset_bytes": ws,
                     "device_calls": calls, "quant_scale": max_scale,

@@ -42,7 +42,7 @@ import numpy as np
 
 from repro import overlay as overlay_api
 from repro.core import selection
-from repro.obs import REGISTRY
+from repro.obs import REGISTRY, span
 from repro.core.construction import default_num_rings
 from repro.core.diameter import adjacency_from_edges, is_edge
 from repro.membership.elastic import HostState, detect_stragglers
@@ -513,32 +513,42 @@ class ChurnEngine:
         self._seq += 1
         heapq.heappush(heap, (t, self._seq, event))
 
+    # Each join, leave and fail runs its host work in one span,
+    # ``engine.policy``: the policy's attach / detach and repairs, its
+    # maybe_adapt, and SWIM detection.  The relaxations it enqueues are
+    # asynchronous dispatches; the tombstone, which may rebuild, runs
+    # outside the span in the rebuild's own spans.
+
     def _confirmed_leave(self, u: int) -> None:
         if not self.alive[u]:
             return
-        repairs = self.policy.detach(u, self.rng)
         self.inc.leave(u)
-        self._pending_failed.discard(u)
-        for a, b in repairs:
-            if self.alive[a] and self.alive[b]:
-                self.inc.add_edge(a, b)
-        self.policy.maybe_adapt(self)
+        with span("engine.policy"):
+            # detach reads only the policy's rings, so it may follow the
+            # tombstone
+            repairs = self.policy.detach(u, self.rng)
+            self._pending_failed.discard(u)
+            for a, b in repairs:
+                if self.alive[a] and self.alive[b]:
+                    self.inc.add_edge(a, b)
+            self.policy.maybe_adapt(self)
 
     def _handle_join(self, u: int) -> None:
         if self.alive[u]:
             return
-        live = self.live_ids()
-        edges = self.policy.attach(self.w, live, self.rng, u)
-        nbrs = set()
-        for a, b in edges:
-            if u not in (a, b):
-                raise ValueError(
-                    f"attach() must return edges incident to the joiner "
-                    f"{u}; got {(a, b)}")
-            nbrs.add(b if a == u else a)
-        nbrs.discard(u)
-        self.inc.join(u, sorted(nbrs))
-        self.policy.maybe_adapt(self)
+        with span("engine.policy"):
+            live = self.live_ids()
+            edges = self.policy.attach(self.w, live, self.rng, u)
+            nbrs = set()
+            for a, b in edges:
+                if u not in (a, b):
+                    raise ValueError(
+                        f"attach() must return edges incident to the joiner "
+                        f"{u}; got {(a, b)}")
+                nbrs.add(b if a == u else a)
+            nbrs.discard(u)
+            self.inc.join(u, sorted(nbrs))
+            self.policy.maybe_adapt(self)
 
     def _handle_fail(self, heap, t: float, u: int) -> None:
         if not self.alive[u] or u in self._pending_failed:
@@ -546,16 +556,17 @@ class ChurnEngine:
         if not self.detect_failures:
             self._confirmed_leave(u)
             return
-        # crashed-but-unconfirmed peers cannot probe or relay: the SWIM
-        # detection runs on the live view minus the other pending victims
-        live = self.live_ids()
-        obs = live[~np.isin(live, list(self._pending_failed))]
-        pos = int(np.searchsorted(obs, u))
-        t_conf = confirmed_leave_time(
-            self.inc.adj[np.ix_(obs, obs)], pos, t_fail=t, cfg=self.swim,
-            seed=int(self.rng.integers(2**31)))
-        self._pending_failed.add(u)
-        self._push(heap, t_conf, Event(time=t_conf, kind="leave", node=u))
+        with span("engine.policy"):
+            # crashed-but-unconfirmed peers cannot probe or relay: the SWIM
+            # detection runs on the live view minus the other pending victims
+            live = self.live_ids()
+            obs = live[~np.isin(live, list(self._pending_failed))]
+            pos = int(np.searchsorted(obs, u))
+            t_conf = confirmed_leave_time(
+                self.inc.adj[np.ix_(obs, obs)], pos, t_fail=t, cfg=self.swim,
+                seed=int(self.rng.integers(2**31)))
+            self._pending_failed.add(u)
+            self._push(heap, t_conf, Event(time=t_conf, kind="leave", node=u))
 
     def _scaled_w(self) -> np.ndarray:
         f = self.latency_factor * self.drift_scale

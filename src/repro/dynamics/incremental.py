@@ -34,6 +34,7 @@ folds a whole (T, B) insert trace into a single ``lax.scan``.
 from __future__ import annotations
 
 import dataclasses
+import weakref
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 from repro.core import batcheval
 from repro.core.diameter import (INF, is_edge, largest_cc_diameter,
                                  relax_edge_update)
-from repro.obs import jit_span
+from repro.obs import REGISTRY, jit_span, span
 
 __all__ = [
     "relax_edge",
@@ -113,6 +114,16 @@ def relax_edge_stream_batched(dists: jnp.ndarray, us: jnp.ndarray,
 
 _cc_diameter = jax.jit(largest_cc_diameter)
 
+# host copies of the device distance matrix (``IncrementalDistances.
+# distances``): each one moves C^2 * 4 bytes, and the span ``dist.to_host``
+# times it
+_HOST_COPIES = REGISTRY.counter(
+    "repro_dist_host_copies_total",
+    "copies of the distance matrix from the device to the host")
+_HOST_BYTES = REGISTRY.counter(
+    "repro_dist_host_bytes_total",
+    "bytes of the distance matrix copied from the device to the host")
+
 
 # ---------------------------------------------------------------------------
 # host-side stateful wrapper
@@ -153,6 +164,7 @@ class IncrementalDistances:
                                       "leaves": 0, "rebuilds": 0,
                                       "events": 0}
         self._dist: Optional[jnp.ndarray] = None
+        self._host_of = None           # weakref to the _dist last read
         self.rebuild()
         self.stats["rebuilds"] = 0       # the initial APSP is not churn cost
 
@@ -172,8 +184,21 @@ class IncrementalDistances:
     @property
     def distances(self) -> np.ndarray:
         """Current (C, C) distance matrix.  Exact when no deletions are
-        pending; otherwise an elementwise lower bound on the live truth."""
-        return np.asarray(self._dist)
+        pending; otherwise an elementwise lower bound on the live truth.
+
+        JAX keeps the host value of each device array, so only the first
+        read after a mutation copies; that copy runs in span
+        ``dist.to_host`` and is counted in ``repro_dist_host_copies_total``
+        and ``repro_dist_host_bytes_total``."""
+        d = self._dist
+        if self._host_of is not None and self._host_of() is d:
+            return np.asarray(d)
+        with span("dist.to_host"):
+            out = np.asarray(d)
+        self._host_of = weakref.ref(d)
+        _HOST_COPIES.inc()
+        _HOST_BYTES.inc(out.nbytes)
+        return out
 
     def live_distances(self) -> np.ndarray:
         live = self.live_ids()
@@ -199,8 +224,7 @@ class IncrementalDistances:
         if self.mode == "full":
             self.rebuild()
             return
-        with jit_span("incremental.relax", key=self.capacity):
-            self._dist = relax_edge(self._dist, u, v, wuv)
+        self._dist = relax_edge(self._dist, u, v, wuv)
         self.stats["relaxations"] += 1
 
     def remove_edge(self, u: int, v: int) -> None:
@@ -229,8 +253,7 @@ class IncrementalDistances:
             return
         row = np.full(self.capacity, float(INF), np.float32)
         row[nbrs] = self.adj[u, nbrs]
-        with jit_span("incremental.join", key=self.capacity):
-            self._dist = join_node(self._dist, jnp.asarray(row), u)
+        self._dist = join_node(self._dist, jnp.asarray(row), u)
         self.stats["relaxations"] += 1
 
     def leave(self, u: int) -> None:
@@ -244,8 +267,7 @@ class IncrementalDistances:
         self.stats["events"] += 1
         self.stats["leaves"] += 1
         if self.mode != "full":        # full mode rebuilds anyway below
-            with jit_span("incremental.tombstone", key=self.capacity):
-                self._dist = tombstone(self._dist, u)
+            self._dist = tombstone(self._dist, u)
         self._note_deletion()
 
     def set_latency(self, u: int, v: int, ms: float) -> None:
@@ -265,8 +287,7 @@ class IncrementalDistances:
         if self.mode == "full":
             self.rebuild()
         elif ms < old_edge:
-            with jit_span("incremental.relax", key=self.capacity):
-                self._dist = relax_edge(self._dist, u, v, np.float32(ms))
+            self._dist = relax_edge(self._dist, u, v, np.float32(ms))
             self.stats["relaxations"] += 1
         elif ms > old_edge:
             self._note_deletion()
@@ -298,10 +319,17 @@ class IncrementalDistances:
         relaxations layered on top of this matrix assume an exact base
         (every served distance is "exact or lower bound"), so a quantized
         rebuild would silently poison that contract.
+
+        Its phases run in spans ``rebuild.to_device`` (the adjacency's
+        upload, and the host side of the result's re-upload here, which is
+        not waited for: the transfer overlaps what follows),
+        ``rebuild.device`` and ``rebuild.to_host``.
         """
         with jit_span("incremental.rebuild", key=self.capacity):
-            self._dist = jnp.asarray(
-                batcheval.apsp_matrices(self.adj[None], dtype="float32")[0])
+            d = batcheval.apsp_matrices(self.adj[None], dtype="float32",
+                                        span_prefix="rebuild")[0]
+            with span("rebuild.to_device"):
+                self._dist = jnp.asarray(d)
         self.pending_deletions = 0
         self.stats["rebuilds"] += 1
 

@@ -107,6 +107,7 @@ def minplus_pallas_batched(
         out_specs=pl.BlockSpec((None, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, m, n), jnp.float32),
         interpret=interpret,
+        name="minplus_batched",
     )(a, b)
 
 
@@ -191,29 +192,29 @@ def apsp_tiled_pallas(d: jnp.ndarray, tile: int = 256,
     nb = n // tile
     dt = d.dtype
 
-    def _call(kernel, grid, in_specs, out_specs, out_shape):
+    def _call(name, kernel, grid, in_specs, out_specs, out_shape):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=jax.ShapeDtypeStruct(out_shape, dt),
-            interpret=interpret)
+            interpret=interpret, name=name)
 
     t = tile
     fw_diag = _call(
-        _fw_diag_kernel, (1,),
+        "fw_diag", _fw_diag_kernel, (1,),
         [pl.BlockSpec((t, t), lambda i: (0, 0))],
         pl.BlockSpec((t, t), lambda i: (0, 0)), (t, t))
     panel_left = _call(
-        _panel_left_kernel, (nb,),
+        "fw_panel_left", _panel_left_kernel, (nb,),
         [pl.BlockSpec((t, t), lambda j: (0, 0)),
          pl.BlockSpec((t, t), lambda j: (0, j))],
         pl.BlockSpec((t, t), lambda j: (0, j)), (t, n))
     panel_right = _call(
-        _panel_right_kernel, (nb,),
+        "fw_panel_right", _panel_right_kernel, (nb,),
         [pl.BlockSpec((t, t), lambda i: (i, 0)),
          pl.BlockSpec((t, t), lambda i: (0, 0))],
         pl.BlockSpec((t, t), lambda i: (i, 0)), (n, t))
     outer = _call(
-        _outer_kernel, (nb, nb),
+        "fw_outer", _outer_kernel, (nb, nb),
         [pl.BlockSpec((t, t), lambda i, j: (i, j)),
          pl.BlockSpec((t, t), lambda i, j: (i, 0)),
          pl.BlockSpec((t, t), lambda i, j: (0, j))],

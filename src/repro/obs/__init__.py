@@ -7,7 +7,8 @@ The measurement substrate for the whole stack (stdlib only):
               label support, Prometheus text + serde-stamped JSON export)
   tracing   — nestable ``span()`` wall-time timers, the JIT-aware
               ``jit_span()`` (first-call compile vs steady-state execute),
-              and ``TimedRLock`` (lock-wait histograms)
+              ``TimedRLock`` (lock wait and hold histograms), each also
+              annotated on the profiler's clock (``annotate()``)
   logsetup  — structured one-line ``key=value`` stdlib logging,
               ``REPRO_LOG_LEVEL``-controlled
 
@@ -22,8 +23,8 @@ layer's overhead at <= 5% of the uninstrumented path.
 from .logsetup import configure, get_logger, kv  # noqa: F401
 from .metrics import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
                       MetricsRegistry, parse_prometheus)
-from .tracing import (TimedRLock, current_span, jit_phase,  # noqa: F401
-                      jit_span, reset_jit_state, span)
+from .tracing import (TimedRLock, annotate, current_span,  # noqa: F401
+                      jit_phase, jit_span, reset_jit_state, span)
 
 # Pre-registered hierarchical-overlay defaults: present (at zero) in every
 # scrape even before ``repro.hier`` is imported, so dashboards and the
@@ -44,6 +45,7 @@ HIER_ROUTE_HOPS = REGISTRY.histogram(
 __all__ = [
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "parse_prometheus", "span", "current_span", "jit_span", "jit_phase",
-    "reset_jit_state", "TimedRLock", "configure", "get_logger", "kv",
+    "reset_jit_state", "TimedRLock", "annotate", "configure", "get_logger",
+    "kv",
     "HIER_CLUSTERS", "HIER_HEADRING_DIAMETER", "HIER_ROUTE_HOPS",
 ]

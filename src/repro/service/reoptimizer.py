@@ -106,10 +106,17 @@ class Reoptimizer:
 
     # -- the loop ---------------------------------------------------------
 
+    def _snapshot_due(self) -> bool:
+        """The snapshot cadence, which applies only with a snapshot
+        directory: without one no snapshot resets the count, and a due
+        cadence would wake the loop forever."""
+        return (bool(self.state.snapshot_dir)
+                and self.state.events_since_snapshot >= self.snapshot_every)
+
     def _due(self) -> bool:
         return (self._forced > 0
                 or self.state.events_since_reopt >= self.every
-                or self.state.events_since_snapshot >= self.snapshot_every)
+                or self._snapshot_due())
 
     def _loop(self) -> None:
         while True:
@@ -124,7 +131,7 @@ class Reoptimizer:
             try:
                 if forced or self.state.events_since_reopt >= self.every:
                     self.step(force=forced)
-                elif self.state.events_since_snapshot >= self.snapshot_every:
+                elif self._snapshot_due():
                     self.state.write_snapshot(reason="cadence")
             except Exception:  # noqa: BLE001 - a failed cycle must not kill the daemon
                 self.last_error = traceback.format_exc()

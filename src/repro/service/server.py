@@ -50,7 +50,8 @@ from urllib.parse import parse_qs, urlparse
 
 from repro import serde
 from repro.dynamics.scenarios import Event, Trace
-from repro.obs import REGISTRY, configure as configure_logging, get_logger, kv
+from repro.obs import (REGISTRY, annotate, configure as configure_logging,
+                       get_logger, kv)
 from repro.obs.metrics import LATENCY_BUCKETS_S
 from repro.runtime import enable_compile_cache
 
@@ -131,8 +132,6 @@ class _Handler(BaseHTTPRequestHandler):
         """Returns the path below /v1, or None after answering an error."""
         path = urlparse(self.path).path.rstrip("/")
         parts = [p for p in path.split("/") if p]
-        if len(parts) > 1 and parts[1] in _ENDPOINTS:
-            self._endpoint = parts[1]
         if not parts or not parts[0].startswith("v"):
             self._error(404, f"endpoints live under /{API_VERSIONS[0]}/")
             return None
@@ -155,13 +154,17 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _instrumented(self, method: str, handler) -> None:
         """Per-endpoint latency histogram + status-code counter around the
-        actual dispatch; the endpoint label is resolved by _route_version
-        and unknown paths fold into ``_unknown``."""
+        actual dispatch, and the annotation ``repro.http.<endpoint>`` on
+        the profiler's clock, under which the request's spans nest; unknown
+        paths fold into ``_unknown``."""
         self._status = 0
-        self._endpoint = "_unknown"
+        parts = [p for p in urlparse(self.path).path.split("/") if p]
+        self._endpoint = (parts[1] if len(parts) > 1
+                          and parts[1] in _ENDPOINTS else "_unknown")
         t0 = time.perf_counter()
         try:
-            handler()
+            with annotate("repro.http." + self._endpoint):
+                handler()
         finally:
             dt = time.perf_counter() - t0
             status = str(self._status or 500)

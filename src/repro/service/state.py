@@ -22,7 +22,11 @@ overestimate.  ``/v1/stats`` exposes which.
 Locking: one ``RLock`` over engine mutations and reads.  Every query is
 O(C) – O(C^2) numpy work; the only expensive operations are the explicit
 ``exact=True`` diameter refresh and the re-optimizer's candidate scoring,
-which runs outside the lock by design.
+which runs outside the lock by design.  Each entry point's body runs in a
+span (``state.ingest``, ``state.route``, ``state.stats``,
+``state.diameter``, ``state.capture``, ``state.commit``) that is open when
+the lock is taken, so ``repro_service_lock_hold_seconds{holder}`` says who
+holds the lock while others wait.
 """
 from __future__ import annotations
 
@@ -38,6 +42,7 @@ from repro.core.diameter import INF, is_edge
 from repro.dynamics.engine import POLICIES, ChurnEngine
 from repro.dynamics.scenarios import Event, Trace
 from repro.obs import REGISTRY, TimedRLock, get_logger, kv, span
+from repro.obs.tracing import SPAN_BUCKETS_S
 from repro.overlay import Overlay
 
 from . import snapshots as snaps
@@ -62,6 +67,11 @@ _SNAPSHOTS = REGISTRY.counter(
 _REOPT_EDGES = REGISTRY.counter(
     "repro_service_reopt_edges_applied_total",
     "re-optimization edges landed as incremental relaxations")
+_LOCK_HOLD = REGISTRY.histogram(
+    "repro_service_lock_hold_seconds",
+    "time the ServiceState lock is held per top-level acquisition, by the "
+    "innermost span open when it was taken (state.ingest, state.route, ...)",
+    labels=("holder",), buckets=SPAN_BUCKETS_S)
 
 _STALE_GAUGE = REGISTRY.gauge(
     "repro_service_stale_entries",
@@ -134,7 +144,7 @@ class ServiceState:
         self.lock = TimedRLock(
             registry=REGISTRY, name="repro_service_lock_wait_seconds",
             help="wait to acquire the ServiceState lock (handler threads "
-                 "vs re-optimizer contention)")
+                 "vs re-optimizer contention)", hold=_LOCK_HOLD)
         self.engine = engine
         self.is_hier = hasattr(engine, "head_inc")
         self.policy_name = policy_name
@@ -298,7 +308,7 @@ class ServiceState:
         """Apply externally-arriving events in order.  Events applied before
         a failure stay applied (the caller sees the index that failed)."""
         applied = 0
-        with self.lock:
+        with span("state.ingest"), self.lock:
             for i, e in enumerate(events):
                 try:
                     applied += self.engine.process(e)
@@ -329,7 +339,7 @@ class ServiceState:
         _QUERIES.labels(kind=kind, bound=bound).inc()
 
     def stats(self) -> Dict:
-        with self.lock:
+        with span("state.stats"), self.lock:
             self._count_query("stats")
             inc = self.engine.inc
             extra = ({"clusters": self.engine.n_clusters,
@@ -358,7 +368,7 @@ class ServiceState:
             }
 
     def diameter(self, exact: bool = False) -> Dict:
-        with self.lock:
+        with span("state.diameter"), self.lock:
             self._count_query("diameter")
             inc = self.engine.inc
             d = inc.diameter(exact=exact)
@@ -386,7 +396,7 @@ class ServiceState:
         of the distance estimate that guided the descent).
         """
         from repro.routing import record_route, route_single_host
-        with self.lock:
+        with span("state.route"), self.lock:
             self._count_query("route")
             inc = self.engine.inc
             if self.is_hier:
@@ -533,18 +543,20 @@ class ServiceState:
         cheap — and the unchanged ``adapt``/``dqn`` machinery runs on it
         as on any flat overlay.
         """
-        with self.lock:
-            if self.is_hier:
-                ov, act, _heads = self._head_ring_copy()
-                return ReoptJob(live=act, overlay=ov, version=self.version)
-            live = self.engine.inc.live_ids().copy()
-            wl = self.engine.w[np.ix_(live, live)].copy()
-            adjl = self.engine.inc.adj[np.ix_(live, live)].copy()
-            version = self.version
-        # Overlay construction is O(C^2) validation — outside the lock
-        ov = Overlay.from_adjacency(wl, adjl, policy=self.policy_name,
-                                    fold_weights=True)
-        return ReoptJob(live=live, overlay=ov, version=version)
+        with span("state.capture"):
+            with self.lock:
+                if self.is_hier:
+                    ov, act, _heads = self._head_ring_copy()
+                    return ReoptJob(live=act, overlay=ov,
+                                    version=self.version)
+                live = self.engine.inc.live_ids().copy()
+                wl = self.engine.w[np.ix_(live, live)].copy()
+                adjl = self.engine.inc.adj[np.ix_(live, live)].copy()
+                version = self.version
+            # Overlay construction is O(C^2) validation — outside the lock
+            ov = Overlay.from_adjacency(wl, adjl, policy=self.policy_name,
+                                        fold_weights=True)
+            return ReoptJob(live=live, overlay=ov, version=version)
 
     def commit_reopt(self, job: ReoptJob, new_overlay: Overlay) -> Dict:
         """Atomically swap the optimized overlay in.
@@ -557,42 +569,43 @@ class ServiceState:
         swap, so a query sees either the old topology or the new one,
         never a half-merged state.
         """
-        new_edges = np.argwhere(np.triu(
-            np.asarray(is_edge(new_overlay.adjacency))
-            & ~np.asarray(is_edge(job.overlay.adjacency)), 1))
-        with self.lock:
-            applied = 0
-            if self.is_hier:
-                # job.live holds CLUSTER ids; land head-ring edges between
-                # clusters that are still active
-                eng = self.engine
-                for i, j in new_edges:
-                    a, b = int(job.live[i]), int(job.live[j])
-                    if (eng.states.get(a) is not None
-                            and eng.states.get(b) is not None
-                            and eng.states[a].head >= 0
-                            and eng.states[b].head >= 0):
-                        eng.head_inc.add_edge(
-                            a, b, float(new_overlay.adjacency[i, j]))
-                        applied += 1
-            else:
-                alive = self.engine.alive
-                for i, j in new_edges:
-                    u, v = int(job.live[i]), int(job.live[j])
-                    if alive[u] and alive[v]:
-                        self.engine.inc.add_edge(
-                            u, v, float(new_overlay.adjacency[i, j]))
-                        applied += 1
-            self.version += 1
-            self.reopts_completed += 1
-            self.events_since_reopt = 0
-            self._overlay = None             # next overlay() serves buffer B
-            _REOPT_EDGES.inc(applied)
-            _log.info(kv("reopt.commit", version=self.version,
-                         edges_added=applied,
-                         edges_proposed=int(len(new_edges))))
-            return {"version": self.version, "edges_added": applied,
-                    "edges_proposed": int(len(new_edges))}
+        with span("state.commit"):
+            new_edges = np.argwhere(np.triu(
+                np.asarray(is_edge(new_overlay.adjacency))
+                & ~np.asarray(is_edge(job.overlay.adjacency)), 1))
+            with self.lock:
+                applied = 0
+                if self.is_hier:
+                    # job.live holds CLUSTER ids; land head-ring edges between
+                    # clusters that are still active
+                    eng = self.engine
+                    for i, j in new_edges:
+                        a, b = int(job.live[i]), int(job.live[j])
+                        if (eng.states.get(a) is not None
+                                and eng.states.get(b) is not None
+                                and eng.states[a].head >= 0
+                                and eng.states[b].head >= 0):
+                            eng.head_inc.add_edge(
+                                a, b, float(new_overlay.adjacency[i, j]))
+                            applied += 1
+                else:
+                    alive = self.engine.alive
+                    for i, j in new_edges:
+                        u, v = int(job.live[i]), int(job.live[j])
+                        if alive[u] and alive[v]:
+                            self.engine.inc.add_edge(
+                                u, v, float(new_overlay.adjacency[i, j]))
+                            applied += 1
+                self.version += 1
+                self.reopts_completed += 1
+                self.events_since_reopt = 0
+                self._overlay = None             # next overlay() serves buffer B
+                _REOPT_EDGES.inc(applied)
+                _log.info(kv("reopt.commit", version=self.version,
+                             edges_added=applied,
+                             edges_proposed=int(len(new_edges))))
+                return {"version": self.version, "edges_added": applied,
+                        "edges_proposed": int(len(new_edges))}
 
     # -- snapshots --------------------------------------------------------
 

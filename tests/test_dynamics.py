@@ -300,3 +300,86 @@ def test_adjacency_from_rings_rejects_non_permutations():
     # valid inputs still pass
     adjacency_from_rings(w, [np.arange(8)])
     adjacency_from_edges(w, [(0, 7)])
+
+
+# ---------------------------------------------------------------------------
+# instrumentation: host copies of D, the rebuild's phases, engine.policy
+# ---------------------------------------------------------------------------
+
+def _span_count(name: str) -> int:
+    from repro.obs import REGISTRY
+    return REGISTRY.get("repro_span_seconds").labels(span=name).count
+
+
+def _host_copies():
+    from repro.obs import REGISTRY
+    return (REGISTRY.get("repro_dist_host_copies_total").value,
+            REGISTRY.get("repro_dist_host_bytes_total").value,
+            _span_count("dist.to_host"))
+
+
+def test_host_copies_of_distances_counted_once_per_mutation():
+    from repro.obs import REGISTRY, parse_prometheus
+    c = 24
+    w, adj, alive = _fresh_state(16, c, seed=5)
+    inc = IncrementalDistances(w, adj, alive)
+    n0, b0, s0 = _host_copies()
+    inc.distances
+    inc.live_distances()                   # a second read: no copy
+    assert _host_copies() == (n0 + 1, b0 + c * c * 4, s0 + 1)
+    inc.join(16, [0, 1])                   # a mutation: the next read copies
+    inc.distances
+    inc.distances
+    assert _host_copies() == (n0 + 2, b0 + 2 * c * c * 4, s0 + 2)
+    # the relax/join/tombstone updates only enqueue: they carry no timing
+    fns = {dict(k).get("fn") for k in parse_prometheus(
+        REGISTRY.render_prometheus()).get("repro_jit_execute_seconds_count",
+                                          {})}
+    assert not fns & {"incremental.relax", "incremental.join",
+                      "incremental.tombstone"}
+
+
+def test_rebuild_phases_in_spans_and_scoring_outside_them():
+    from repro.core import batcheval
+    from repro.obs import REGISTRY
+    w, adj, alive = _fresh_state(16, 24, seed=6)
+    inc = IncrementalDistances(w, adj, alive)
+    phases = ("rebuild.to_device", "rebuild.device", "rebuild.to_host")
+    hist = REGISTRY.get("repro_span_seconds")
+    before = {p: (hist.labels(span=p).count, hist.labels(span=p).sum)
+              for p in phases}
+    jit = REGISTRY.get("repro_jit_execute_seconds").labels(
+        fn="incremental.rebuild")
+    jit0 = (jit.count, jit.sum)
+    inc.rebuild()
+    grew = {p: (hist.labels(span=p).count - before[p][0],
+                hist.labels(span=p).sum - before[p][1]) for p in phases}
+    # the adjacency's upload and the result's re-upload, one device call,
+    # one download
+    assert [grew[p][0] for p in phases] == [2, 1, 1]
+    assert jit.count == jit0[0] + 1
+    assert sum(g[1] for g in grew.values()) <= jit.sum - jit0[1]
+    counts = [hist.labels(span=p).count for p in phases]
+    batcheval.diameters(adj[None])         # re-optimizer scoring path
+    batcheval.apsp_matrices(adj[None])
+    assert [hist.labels(span=p).count for p in phases] == counts
+
+
+def test_engine_policy_span_once_per_applied_event():
+    trace = SCENARIOS["poisson_churn"](n0=18, seed=1)
+    events, n_fail = [], 0
+    for i, e in enumerate(sorted(trace.events, key=lambda e: e.time)):
+        if e.kind == "leave" and i % 3 == 0:      # some crash instead
+            e = Event(time=e.time, kind="fail", node=e.node)
+            n_fail += 1
+        events.append(e)
+    assert n_fail and {e.kind for e in events} == {"join", "leave", "fail"}
+    eng = ChurnEngine(Trace(n0=trace.n0, capacity=trace.capacity,
+                            dist=trace.dist, seed=trace.seed, events=[],
+                            name="policy-span"),
+                      DGROPolicy(), seed=0, detect_failures=True)
+    before = _span_count("engine.policy")
+    applied = sum(eng.process(e) for e in events) + eng.flush()
+    # each fail is applied twice: the crash, then its SWIM-confirmed leave
+    assert applied == len(events) + n_fail
+    assert _span_count("engine.policy") - before == applied

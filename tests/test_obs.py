@@ -12,11 +12,17 @@ Covers the PR's satellites explicitly:
 * ``GET /v1/metrics`` answers while a re-optimization cycle is in flight,
   and scraped counters match the workload exactly;
 * durations come from the monotonic clock — a wall-clock step cannot
-  corrupt ``uptime_s``.
+  corrupt ``uptime_s``;
+* spans nest on the profiler's clock: in a CPU trace a route request's
+  ``repro.state.route`` encloses its ``repro.dist.to_host`` on one thread,
+  and importing ``repro.obs`` loads no jax.
 """
 from __future__ import annotations
 
 import logging
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -25,6 +31,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from conftest import subproc_env
 from repro import serde
 from repro.dynamics.scenarios import Event, Trace, poisson_churn
 from repro.obs import (REGISTRY, Histogram, MetricsRegistry, TimedRLock,
@@ -237,6 +244,90 @@ def test_timed_rlock_reentrant_and_records_waits():
     t2.join()
     assert hist.count == 3
     assert hist.quantile(1.0) >= 0.01   # the contended acquire waited
+
+
+def test_timed_rlock_hold_labelled_by_acquiring_span():
+    reg = MetricsRegistry()
+    hold = reg.histogram("hold_seconds", "h", labels=("holder",))
+    lock = TimedRLock(registry=reg, name="w_seconds", help="w", hold=hold)
+    with span("state.ingest", registry=reg):
+        with lock:
+            with span("dist.to_host", registry=reg), lock:  # re-entrant
+                time.sleep(0.01)
+    with lock:                          # no span open: "other"
+        pass
+    assert hold.labels(holder="state.ingest").count == 1
+    assert hold.labels(holder="state.ingest").sum >= 0.01
+    assert hold.labels(holder="other").count == 1
+    assert hold.labels(holder="dist.to_host").count == 0
+    assert reg.get("w_seconds").count == 2
+
+
+def _repro_events(trace_dir: str):
+    """``{thread line: [(name, start_ns, end_ns)]}`` of the ``repro.*``
+    events on the host plane of the one trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    files = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(files) == 1, files
+    lines = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith("repro.")]
+            if evs:
+                lines[(plane.name, i)] = evs
+    return lines
+
+
+def test_route_spans_nest_on_the_profiler_clock(tmp_path):
+    import jax
+    state = ServiceState.fresh(_world(), policy="dgro", seed=0)
+    server = ServiceServer(state, reopt_enabled=False).start()
+    try:
+        c = ServiceClient(server.url)
+        c.wait_ready(timeout=30)
+        c.post_events(_events(events=6))     # a mutation: the next read copies
+        nodes = c.adjacency()["nodes"]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            c.route(nodes[0], nodes[-1])
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        server.stop(final_snapshot=False)
+
+    def inside(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    nested = []
+    for evs in _repro_events(str(tmp_path)).values():
+        by = {}
+        for ev in evs:
+            by.setdefault(ev[0], []).append(ev)
+        nested += [(h, r, d)
+                   for h in by.get("repro.http.route", [])
+                   for r in by.get("repro.state.route", [])
+                   for d in by.get("repro.dist.to_host", [])
+                   if inside(r, h) and inside(d, r)]
+    assert len(nested) == 1, _repro_events(str(tmp_path))
+
+
+def test_importing_obs_loads_no_jax():
+    code = ("import sys\n"
+            "import repro.obs as obs\n"
+            "with obs.span('x'), obs.annotate('y'):\n"
+            "    pass\n"
+            "lock = obs.TimedRLock(name='t_seconds', help='t')\n"
+            "with lock:\n"
+            "    pass\n"
+            "print('JAX', 'jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=subproc_env(), cwd=".", timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == "JAX False", out.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ import json
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -280,6 +281,49 @@ def test_reopt_commit_swaps_atomically_and_improves():
     assert state.version >= 1
     d1 = state.diameter(exact=True)["diameter"]
     assert d1 <= d0 + 1e-5             # added edges only relax distances
+
+
+def test_lock_hold_recorded_by_holder_span():
+    from repro.obs import REGISTRY
+    state = ServiceState.fresh(_world(), policy="dgro", seed=0)
+    hold = REGISTRY.get("repro_service_lock_hold_seconds")
+    wait = REGISTRY.get("repro_service_lock_wait_seconds")
+
+    def counts():
+        return {h: hold.labels(holder=h).count
+                for h in ("state.ingest", "state.route", "other")}
+
+    c0, w0 = counts(), wait.count
+    events = _trace(events=4).events
+    with state.lock:                 # the ingest below re-enters: not counted
+        state.ingest(events[:2])
+    state.ingest(events[2:])
+    live = state.engine.live_ids()
+    state.route(int(live[0]), int(live[-1]))
+    c1 = counts()
+    assert c1["state.ingest"] - c0["state.ingest"] == 1
+    assert c1["state.route"] - c0["state.route"] == 1
+    assert c1["other"] - c0["other"] == 1
+    assert wait.count - w0 == 3
+
+
+def test_reoptimizer_ignores_snapshot_cadence_without_a_directory():
+    state = ServiceState.fresh(_world(), policy="dgro", seed=0)
+    assert state.snapshot_dir is None
+    reopt = Reoptimizer(state, every=2**31, snapshot_every=4, seed=0)
+    state.events_since_snapshot = 4
+    assert not reopt._due()
+    written = []
+    state.write_snapshot = lambda reason="periodic": written.append(reason)
+    reopt.start()
+    try:
+        reopt.notify()
+        time.sleep(0.3)
+    finally:
+        reopt.stop()
+    assert written == [] and reopt.cycles == 0
+    state.snapshot_dir = "unused"    # with a directory the cadence applies
+    assert reopt._due()
 
 
 # ---------------------------------------------------------------------------
