@@ -21,7 +21,9 @@ Layout of the module:
   (min-plus squaring; batched Pallas kernel on TPU), and ``"tiled"``
   (blocked Floyd-Warshall over a (N/T, N/T) block grid —
   ``kernels.minplus.apsp_tiled`` — whose working set is panels, not cubes;
-  the TPU default past ``REPRO_APSP_TILED_N`` nodes).
+  the TPU default past ``REPRO_APSP_TILED_N`` nodes).  ``apsp_device``
+  runs it on a stack already on the device, with the facade's option
+  resolution, and leaves the result there (the churn engine's rebuild).
 * host facade — ``diameters`` / ``apsp_matrices`` / ``diameters_of_rings``
   STREAM the batch through fixed-size chunks (``default_chunk`` sizes them
   from a per-method memory model, ``REPRO_APSP_MEM_BYTES`` overrides the
@@ -35,7 +37,7 @@ Layout of the module:
   the ROW-BLOCK axis of one huge matrix (min-plus squaring with an
   all-gather per squaring, following the ``parallel_ring_shmap`` pattern).
 
-Instrumentation: every engine call lands in the pre-registered
+Instrumentation: every facade call lands in the pre-registered
 ``repro_apsp_seconds{method, phase}`` histogram (compile/execute split via
 ``obs.jit_phase``) and updates the ``repro_apsp_workingset_bytes`` gauge
 with the modeled per-call device footprint; quantized evals record their
@@ -56,7 +58,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.obs import REGISTRY, jit_phase, jit_span, span
+from repro.obs import REGISTRY, jit_phase, jit_span
 from repro.obs.tracing import SPAN_BUCKETS_S
 
 from .diameter import INF, is_edge, largest_cc_diameter
@@ -74,6 +76,7 @@ __all__ = [
     "diameters_of_rings",
     "diameters_sharded",
     "apsp_matrices",
+    "apsp_device",
     "apsp_rowshard",
     "quantize_latency",
     "eval_options",
@@ -486,6 +489,31 @@ def quantize_latency(adjs: np.ndarray, bits: int = 16):
 # host facade (streaming)
 # ---------------------------------------------------------------------------
 
+def _resolve_engine(n: int, use_kernel: bool, method: Optional[str],
+                    tile: Optional[int]) -> tuple:
+    """``(use_kernel, method, tile)`` for an N-node engine call, each
+    resolved through ``eval_options`` / ``REPRO_APSP_*`` / the default."""
+    use_kernel = bool(use_kernel or _opt("use_kernel"))
+    method = _opt("method", method)
+    if method is None:
+        method = _auto_method(use_kernel, n,
+                              int(os.environ.get("REPRO_APSP_TILED_N",
+                                                 DEFAULT_TILED_N)))
+    assert method in METHODS, method
+    return use_kernel, method, _opt("tile", tile)
+
+
+def apsp_device(adjs: jnp.ndarray) -> jnp.ndarray:
+    """float32 ``batched_apsp`` of a (B, N, N) stack that is already on the
+    device, with the facade's method / tile resolution; the result stays on
+    the device.  The churn engine's rebuild runs it on its live
+    sub-matrix, as one program named ``batched_apsp``."""
+    use_kernel, method, tile = _resolve_engine(adjs.shape[-1], False, None,
+                                               None)
+    return batched_apsp(adjs, use_kernel=use_kernel, method=method,
+                        dtype="float32", tile=tile)
+
+
 def _observe_call(method: str, key, seconds: float, ws_bytes: int) -> None:
     if not REGISTRY.enabled:
         return
@@ -495,29 +523,13 @@ def _observe_call(method: str, key, seconds: float, ws_bytes: int) -> None:
     _APSP_WORKINGSET.set(ws_bytes)
 
 
-def _phased_call(fn, blk: np.ndarray, prefix: str) -> np.ndarray:
-    """``np.asarray(fn(blk))`` with its upload, device work and download in
-    spans ``<prefix>.to_device``, ``<prefix>.device`` and
-    ``<prefix>.to_host``.  The download waits for the result anyway, so
-    waiting for it one step earlier splits the time without a sync of its
-    own."""
-    with span(prefix + ".to_device"):
-        x = jnp.asarray(blk).block_until_ready()
-    with span(prefix + ".device"):
-        y = jax.block_until_ready(fn(x))
-    with span(prefix + ".to_host"):
-        return np.asarray(y)
-
-
 def _stream(src, b: int, n: int, fn, *, chunk: int, method: str,
             compute_dtype: str, quantize: bool, symmetric: bool,
-            use_kernel: bool, tile: Optional[int], ws_bytes: int,
-            span_prefix: Optional[str] = None):
+            use_kernel: bool, tile: Optional[int], ws_bytes: int):
     """Drive ``fn`` over fixed-size chunks of ``src``, never holding more
     than one (chunk, N, N) block on host or device.  The trailing partial
     chunk is padded by repeating its first matrix so every device call has
-    the SAME compiled shape (one trace, not one per remainder).  With a
-    ``span_prefix`` each chunk's phases run in spans (``_phased_call``)."""
+    the SAME compiled shape (one trace, not one per remainder)."""
     outs = []
     calls = 0
     max_scale = 0.0
@@ -532,10 +544,7 @@ def _stream(src, b: int, n: int, fn, *, chunk: int, method: str,
             blk = np.concatenate(
                 [blk, np.repeat(blk[:1], chunk - (hi - lo), axis=0)], axis=0)
         t0 = time.perf_counter()
-        if span_prefix is None:
-            res = np.asarray(fn(jnp.asarray(blk)))
-        else:
-            res = _phased_call(fn, blk, span_prefix)
+        res = np.asarray(fn(jnp.asarray(blk)))
         _observe_call(method,
                       (blk.shape[0], n, use_kernel, method, symmetric,
                        compute_dtype, tile),
@@ -569,16 +578,9 @@ def diameters(adjs, *, use_kernel: bool = False, method: str | None = None,
     b, n = len(src), src.n
     if b == 0:
         return np.zeros((0,), np.float32)
-    use_kernel = bool(use_kernel or _opt("use_kernel"))
-    method = _opt("method", method)
-    if method is None:
-        method = _auto_method(use_kernel, n,
-                              int(os.environ.get("REPRO_APSP_TILED_N",
-                                                 DEFAULT_TILED_N)))
-    assert method in METHODS, method
+    use_kernel, method, tile = _resolve_engine(n, use_kernel, method, tile)
     dtype = _opt("dtype", dtype) or "float32"
     assert dtype in DTYPES, dtype
-    tile = _opt("tile", tile)
     chunk = _opt("chunk", chunk) or default_chunk(
         n, method, dtype=dtype, tile=tile, use_kernel=use_kernel)
     rtol = _opt("exact_rtol", exact_rtol)
@@ -644,8 +646,7 @@ def _verify_quantized(src, b: int, out: np.ndarray, rtol: Optional[float],
 def apsp_matrices(adjs, *, use_kernel: bool = False,
                   method: str | None = None, symmetric: bool = True,
                   chunk: int | None = None, dtype: str | None = None,
-                  tile: int | None = None,
-                  span_prefix: str | None = None) -> np.ndarray:
+                  tile: int | None = None) -> np.ndarray:
     """Full (B, N, N) float32 APSP distance matrices, streamed per chunk.
 
     The matrix-returning sibling of ``diameters`` for consumers that need
@@ -654,22 +655,14 @@ def apsp_matrices(adjs, *, use_kernel: bool = False,
     instrumentation, with the result re-widened to float32 on host.  The
     HOST output is dense (the caller asked for it); only device memory is
     bounded.  No probe-verification here — reduced precision is the
-    caller's explicit contract for distances.  ``span_prefix`` names the
-    spans of each chunk's upload, device work and download (the churn
-    engine's rebuild passes ``"rebuild"``).
+    caller's explicit contract for distances.
     """
     src = _as_source(adjs)
     b, n = len(src), src.n
     if b == 0:
         return np.zeros((0, n, n), np.float32)
-    use_kernel = bool(use_kernel or _opt("use_kernel"))
-    method = _opt("method", method)
-    if method is None:
-        method = _auto_method(use_kernel, n,
-                              int(os.environ.get("REPRO_APSP_TILED_N",
-                                                 DEFAULT_TILED_N)))
+    use_kernel, method, tile = _resolve_engine(n, use_kernel, method, tile)
     dtype = _opt("dtype", dtype) or "float32"
-    tile = _opt("tile", tile)
     chunk = _opt("chunk", chunk) or default_chunk(
         n, method, dtype=dtype, tile=tile, use_kernel=use_kernel)
     compute_dtype = "bfloat16" if dtype == "bfloat16" else "float32"
@@ -684,8 +677,7 @@ def apsp_matrices(adjs, *, use_kernel: bool = False,
     outs, calls, max_scale = _stream(
         src, b, n, fn, chunk=chunk, method=method,
         compute_dtype=compute_dtype, quantize=dtype == "int16",
-        symmetric=symmetric, use_kernel=use_kernel, tile=tile, ws_bytes=ws,
-        span_prefix=span_prefix)
+        symmetric=symmetric, use_kernel=use_kernel, tile=tile, ws_bytes=ws)
     _report.data = {"b": b, "n": n, "method": method, "dtype": dtype,
                     "chunk": chunk, "tile": tile, "workingset_bytes": ws,
                     "device_calls": calls, "quant_scale": max_scale,

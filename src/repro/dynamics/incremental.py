@@ -16,11 +16,19 @@ be repaired far cheaper:
   which a relaxation cannot express.  The node is tombstoned (isolated in
   the adjacency, its distance row/col set to INF) and a bounded staleness
   counter is incremented; when accumulated deletions exceed
-  ``rebuild_threshold`` a full batched rebuild runs through
-  ``repro.core.batcheval``.  Between rebuilds the matrix is a *lower
-  bound*: stale entries may still use paths through departed nodes, so
-  ``D_stale <= D_true`` elementwise — ``refresh()`` restores exactness on
-  demand.
+  ``rebuild_threshold`` a from-scratch rebuild runs.  Between rebuilds the
+  matrix is a *lower bound*: stale entries may still use paths through
+  departed nodes, so ``D_stale <= D_true`` elementwise — ``refresh()``
+  restores exactness on demand.
+
+The rebuild costs O(n_live^3), not O(capacity^3): a dead slot is an
+isolated singleton, so every Floyd–Warshall update through it is a no-op.
+It gathers the live sub-matrix on the device, padded to a multiple of
+256 slots (at most the capacity), runs ``batcheval.apsp_device`` on it,
+and scatters the result back into the (capacity, capacity) matrix.  Each
+padded size is its own program: construction compiles the one that holds
+n_live and the one above it; a rebuild in any other compiles under the
+caller's lock (a fleet that grows or shrinks by more than 256 slots).
 
 All device math is jit'd with static shapes: the state is allocated at a
 fixed ``capacity`` and dead slots are isolated singletons, which the
@@ -124,6 +132,47 @@ _HOST_BYTES = REGISTRY.counter(
     "repro_dist_host_bytes_total",
     "bytes of the distance matrix copied from the device to the host")
 
+# The rebuild's size m, a multiple of this many slots (or the capacity).
+# At a multiple of 256, ``kernels.minplus.ops.default_tile`` tiles the
+# blocked FW at 256 with no second pad.
+_BUCKET = 256
+
+_REBUILD_SLOTS = REGISTRY.counter(
+    "repro_rebuild_slots_total",
+    "slots each rebuild's Floyd-Warshall ran over: the live count padded "
+    "to a bucket, or the capacity")
+_REBUILD_UPDATES = REGISTRY.counter(
+    "repro_rebuild_updates_total",
+    "min-plus updates of the rebuilds' Floyd-Warshall, slots cubed")
+
+
+def _rebuild_slots(n_live: int, capacity: int) -> int:
+    """The rebuild's size: ``n_live`` rounded up to a whole number of
+    buckets (at least one), or the capacity where that reaches it."""
+    m = -(-max(n_live, 1) // _BUCKET) * _BUCKET
+    return min(m, capacity)
+
+
+@jax.jit
+def _gather_live(adj: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """(1, m, m) sub-matrix ``adj[idx][:, idx]``.  An index past the end
+    pads: an isolated singleton, INF row and column with a 0 diagonal."""
+    sub = jnp.take(jnp.take(adj, idx, axis=0, mode="fill", fill_value=INF),
+                   idx, axis=1, mode="fill", fill_value=INF)
+    return jnp.where(jnp.diag(idx >= adj.shape[0]), 0.0, sub)[None]
+
+
+@jax.jit
+def _scatter_live(sub: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+    """(C, C) distances from the (1, m, m) live result, where ``pos[s]``
+    is slot s's place in it, or past the end for a dead slot, which comes
+    out an isolated singleton.  A gather by this inverse index scatters
+    the result back with no sort of its m^2 target positions."""
+    m = sub.shape[-1]
+    out = jnp.take(jnp.take(sub[0], pos, axis=0, mode="fill", fill_value=INF),
+                   pos, axis=1, mode="fill", fill_value=INF)
+    return jnp.where(jnp.diag(pos >= m), 0.0, out)
+
 
 # ---------------------------------------------------------------------------
 # host-side stateful wrapper
@@ -165,6 +214,11 @@ class IncrementalDistances:
                                       "events": 0}
         self._dist: Optional[jnp.ndarray] = None
         self._host_of = None           # weakref to the _dist last read
+        # run the bucket above n_live's too (the same distances, more
+        # pads), so that churn across their edge compiles nothing later
+        m = _rebuild_slots(self.n_live + _BUCKET, self.capacity)
+        if m != _rebuild_slots(self.n_live, self.capacity):
+            self._rebuild(m)
         self.rebuild()
         self.stats["rebuilds"] = 0       # the initial APSP is not churn cost
 
@@ -311,8 +365,8 @@ class IncrementalDistances:
             self.rebuild()
 
     def rebuild(self) -> None:
-        """Full from-scratch APSP over the live adjacency via the
-        instrumented ``batcheval`` engine; resets the staleness counter.
+        """From-scratch APSP over the live adjacency; resets the staleness
+        counter.  The result stays on the device.
 
         Precision is PINNED to float32 regardless of ambient
         ``eval_options`` / ``REPRO_APSP_*`` overrides: the incremental
@@ -321,17 +375,34 @@ class IncrementalDistances:
         rebuild would silently poison that contract.
 
         Its phases run in spans ``rebuild.to_device`` (the adjacency's
-        upload, and the host side of the result's re-upload here, which is
-        not waited for: the transfer overlaps what follows),
-        ``rebuild.device`` and ``rebuild.to_host``.
+        upload) and ``rebuild.device`` (gather, Floyd–Warshall, scatter).
+        Each rebuild adds its size m to ``repro_rebuild_slots_total`` and
+        m^3 to ``repro_rebuild_updates_total``.
         """
-        with jit_span("incremental.rebuild", key=self.capacity):
-            d = batcheval.apsp_matrices(self.adj[None], dtype="float32",
-                                        span_prefix="rebuild")[0]
-            with span("rebuild.to_device"):
-                self._dist = jnp.asarray(d)
+        self._rebuild(_rebuild_slots(self.n_live, self.capacity))
         self.pending_deletions = 0
         self.stats["rebuilds"] += 1
+
+    def _rebuild(self, m: int) -> None:
+        """``self._dist`` from a Floyd–Warshall over ``m`` slots: the live
+        ones in ascending order, so the pivots keep their order, then
+        pads.  Each size m is its own program, so the span's key is
+        (capacity, m): the first rebuild at a size is timed as a compile."""
+        live = self.live_ids()
+        idx = np.full(m, self.capacity, np.int32)
+        idx[:live.size] = live
+        pos = np.full(self.capacity, m, np.int32)
+        pos[live] = np.arange(live.size)
+        with jit_span("incremental.rebuild", key=(self.capacity, m)):
+            with span("rebuild.to_device"):
+                adj = jnp.asarray(self.adj).block_until_ready()
+            with span("rebuild.device"):
+                sub = batcheval.apsp_device(
+                    _gather_live(adj, jnp.asarray(idx)))
+                self._dist = jax.block_until_ready(
+                    _scatter_live(sub, jnp.asarray(pos)))
+        _REBUILD_SLOTS.inc(m)
+        _REBUILD_UPDATES.inc(m ** 3)
 
     def refresh(self) -> None:
         """Restore exactness if deletions are pending."""

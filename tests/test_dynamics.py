@@ -163,6 +163,129 @@ def test_batched_relax_matches_sequential():
 
 
 # ---------------------------------------------------------------------------
+# rebuild over the live slots only
+# ---------------------------------------------------------------------------
+
+def _sparse_state(n_live: int, capacity: int, seed: int):
+    """Live slots scattered over the capacity (a random alive mask), joined
+    by three random rings over them."""
+    w = make_latency("uniform", capacity, seed=seed)
+    rng = np.random.default_rng(seed)
+    live = np.sort(rng.choice(capacity, size=n_live, replace=False))
+    alive = np.zeros(capacity, bool)
+    alive[live] = True
+    edges = np.concatenate([ring_edges(rng.permutation(live))
+                            for _ in range(3)]) if n_live > 1 else []
+    return w, adjacency_from_edges(w, edges), alive
+
+
+def _rebuild_counters():
+    from repro.obs import REGISTRY
+    return (REGISTRY.get("repro_rebuild_slots_total").value,
+            REGISTRY.get("repro_rebuild_updates_total").value)
+
+
+# (capacity, n_live, method, slots): random masks, n_live at a bucket's
+# edge (256, 257), one live node, every slot live (the gather is the
+# identity)
+_REBUILD_CASES = [(640, 241, "fw", 256), (1024, 256, "fw", 256),
+                  (1024, 257, "fw", 512), (1024, 1, "fw", 256),
+                  (384, 384, "fw", 384), (640, 241, "tiled", 256),
+                  (1024, 257, "tiled", 512)]
+
+
+@pytest.mark.parametrize("capacity,n_live,method,slots", _REBUILD_CASES)
+def test_live_slot_rebuild_equals_full_capacity_rebuild(capacity, n_live,
+                                                        method, slots):
+    """The rebuild over the live slots gives the Floyd–Warshall of the whole
+    (capacity, capacity) adjacency: bit for bit under ``fw``, within 1e-6
+    relative under the blocked ``tiled`` method, whose 256-blocks group
+    the additions differently."""
+    from repro.core import batcheval
+    w, adj, alive = _sparse_state(n_live, capacity, seed=capacity + n_live)
+    with batcheval.eval_options(method=method):
+        inc = IncrementalDistances(w, adj, alive)
+        want = batcheval.apsp_matrices(inc.adj[None])[0]
+    got = np.asarray(inc.distances)
+    assert incr._rebuild_slots(inc.n_live, capacity) == slots
+    if method == "fw":
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("capacity,n_live", [(640, 241), (1024, 1)])
+def test_live_slot_rebuild_leaves_dead_slots_isolated(capacity, n_live):
+    w, adj, alive = _sparse_state(n_live, capacity, seed=7)
+    inc = IncrementalDistances(w, adj, alive)
+    inc.leave(int(inc.live_ids()[0]))
+    inc.rebuild()
+    d = np.asarray(inc.distances)
+    dead = np.flatnonzero(~inc.alive)
+    expect = np.full((dead.size, capacity), INF, np.float32)
+    expect[np.arange(dead.size), dead] = 0.0
+    np.testing.assert_array_equal(d[dead], expect)
+    np.testing.assert_array_equal(d[:, dead], expect.T)
+
+
+@pytest.mark.parametrize("capacity,n_live,slots", [(640, 241, 256),
+                                                   (640, 300, 512),
+                                                   (384, 384, 384)])
+def test_rebuild_counts_its_slots_and_updates(capacity, n_live, slots):
+    w, adj, alive = _sparse_state(n_live, capacity, seed=3)
+    inc = IncrementalDistances(w, adj, alive)
+    s0, u0 = _rebuild_counters()
+    inc.rebuild()
+    inc.rebuild()
+    assert _rebuild_counters() == (s0 + 2 * slots, u0 + 2 * slots ** 3)
+
+
+def test_rebuild_in_a_new_bucket_is_timed_as_a_compile():
+    """Construction compiles the bucket that holds n_live (512) and the one
+    above (the capacity, 704); a rebuild in a third bucket (256) lands in
+    the compile histogram, and the next one there in the execute one."""
+    from repro.obs import REGISTRY
+    w, adj, alive = _sparse_state(300, 704, seed=8)
+    inc = IncrementalDistances(w, adj, alive, rebuild_threshold=1000)
+    for node in inc.live_ids()[:50]:
+        inc.leave(int(node))
+    assert incr._rebuild_slots(inc.n_live, inc.capacity) == 256
+    hists = [REGISTRY.get(f"repro_jit_{phase}_seconds").labels(
+        fn="incremental.rebuild") for phase in ("compile", "execute")]
+    counts = [h.count for h in hists]
+    inc.rebuild()
+    assert [h.count for h in hists] == [counts[0] + 1, counts[1]]
+    inc.rebuild()
+    assert [h.count for h in hists] == [counts[0] + 1, counts[1] + 1]
+    _assert_live_parity(inc, "after a rebuild in a new bucket")
+
+
+def test_churn_across_a_bucket_edge_traces_no_new_program():
+    """Construction runs the bucket that holds n_live and compiles the one
+    above it, so churn across their edge (256 <-> 257) traces nothing."""
+    from repro.core import batcheval
+    w, adj, alive = _sparse_state(256, 768, seed=4)
+    inc = IncrementalDistances(w, adj, alive, rebuild_threshold=100)
+    live = inc.live_ids()
+    inc.join(int(np.flatnonzero(~inc.alive)[0]), [int(live[0])])
+    inc.leave(int(live[1]))               # the relax/join/tombstone programs
+    programs = (incr._gather_live, incr._scatter_live, batcheval.batched_apsp)
+    before = [f._cache_size() for f in programs]
+    sizes = []
+    for step in range(4):
+        if step % 2:
+            inc.leave(int(inc.live_ids()[-1]))
+        else:
+            inc.join(int(np.flatnonzero(~inc.alive)[0]),
+                     [int(x) for x in inc.live_ids()[:2]])
+        inc.rebuild()
+        sizes.append(incr._rebuild_slots(inc.n_live, inc.capacity))
+    assert sizes == [512, 256, 512, 256]
+    assert [f._cache_size() for f in programs] == before
+    _assert_live_parity(inc, "after edge churn")
+
+
+# ---------------------------------------------------------------------------
 # traces + scenarios
 # ---------------------------------------------------------------------------
 
@@ -354,9 +477,9 @@ def test_rebuild_phases_in_spans_and_scoring_outside_them():
     inc.rebuild()
     grew = {p: (hist.labels(span=p).count - before[p][0],
                 hist.labels(span=p).sum - before[p][1]) for p in phases}
-    # the adjacency's upload and the result's re-upload, one device call,
-    # one download
-    assert [grew[p][0] for p in phases] == [2, 1, 1]
+    # the adjacency's upload and one device phase; the result stays on the
+    # device, so nothing is downloaded
+    assert [grew[p][0] for p in phases] == [1, 1, 0]
     assert jit.count == jit0[0] + 1
     assert sum(g[1] for g in grew.values()) <= jit.sum - jit0[1]
     counts = [hist.labels(span=p).count for p in phases]

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.dynamics import incremental
 from repro.kernels.minplus import kernel, ops
 
 
@@ -59,11 +60,27 @@ def test_minplus_batched_compiles(one_chip, shape, block):
 
 @pytest.mark.parametrize("n,dtype", [(4096, jnp.float32),
                                      (4096, jnp.bfloat16),
-                                     (986, jnp.float32)])
+                                     (986, jnp.float32),
+                                     (4352, jnp.float32)])
 def test_apsp_tiled_compiles(one_chip, n, dtype):
-    """The auto tile at N=4096 and at the paper's unaligned FABRIC fleet
-    (N=986 = 17 sites x 58 nodes), through the ``ops`` entry point."""
+    """The auto tile at N=4096, at the paper's unaligned FABRIC fleet
+    (N=986 = 17 sites x 58 nodes), and at 4352 = 17 x 256, a rebuild's
+    live-slot size, through the ``ops`` entry point."""
     text = _compiled_text(
         lambda d: ops.apsp_tiled(d, force_kernel=True, interpret=False),
         one_chip, ((n, n), dtype))
     assert "tpu_custom_call" in text
+
+
+def test_live_slot_gather_and_scatter_compile_without_a_sort(one_chip):
+    """The rebuild's gather of 4352 live slots out of 8192, and its way
+    back, at the real sizes: the way back is a gather by the inverse index
+    (a 2-D scatter of the m^2 entries sorts them, and took 22 s to
+    compile)."""
+    c, m = 8192, 4352
+    for fn, shapes in [(incremental._gather_live,
+                        [((c, c), jnp.float32), ((m,), jnp.int32)]),
+                       (incremental._scatter_live,
+                        [((1, m, m), jnp.float32), ((c,), jnp.int32)])]:
+        text = _compiled_text(fn, one_chip, *shapes)
+        assert " gather(" in text and " sort(" not in text
