@@ -378,7 +378,8 @@ class ServiceState:
                     "n_live": inc.n_live, "version": self.version}
 
     def route(self, src: int, dst: int) -> Dict:
-        """Distance + greedy next-hop path from the maintained matrix.
+        """Distance + greedy next-hop path from the maintained matrix's
+        column ``D[:, dst]``, the one part of it a route reads.
 
         The distance is exact when no deletions are pending, otherwise a
         provable lower bound.  The path comes from the SHARED greedy
@@ -407,8 +408,8 @@ class ServiceState:
                                      f"[0, {inc.capacity})")
                 if not inc.alive[u]:
                     raise ValueError(f"{name}={u} is not a live node")
-            D = inc.distances
-            d = float(D[src, dst])
+            col = inc.column(dst)
+            d = float(col[src])
             reachable = d < float(INF) / 2
             stale = inc.pending_deletions > 0
             bound = "lower" if stale else "exact"
@@ -417,8 +418,7 @@ class ServiceState:
             stretch: Optional[float] = None
             if reachable:
                 walk, lat, n_hops, outcome = route_single_host(
-                    np.asarray(inc.adj, np.float32),
-                    np.asarray(D[:, dst], np.float32), src, dst,
+                    np.asarray(inc.adj, np.float32), col, src, dst,
                     policy="latency", hop_budget=int(inc.n_live))
                 if outcome == "delivered":
                     path, hops = walk, n_hops

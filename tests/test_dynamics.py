@@ -285,6 +285,43 @@ def test_churn_across_a_bucket_edge_traces_no_new_program():
     _assert_live_parity(inc, "after edge churn")
 
 
+def _host_columns(inc: IncrementalDistances) -> np.ndarray:
+    """Every column read through ``column``, stacked back into (C, C)."""
+    return np.stack([inc.column(j) for j in range(inc.capacity)], axis=1)
+
+
+@pytest.mark.parametrize("threshold", [3, 100])
+def test_column_equals_host_column_after_every_churn_event(threshold):
+    """``column(j)`` is ``distances[:, j]`` bit for bit after joins,
+    ``add_edge`` relaxations and leaves, with rebuilds (threshold 3) or
+    with the deletions left pending (100: a stale lower bound)."""
+    w, adj, alive = _fresh_state(14, 20, seed=threshold)
+    inc = IncrementalDistances(w, adj, alive, rebuild_threshold=threshold)
+    rng = np.random.default_rng(threshold + 1)
+    for i, _ in enumerate(_random_ops(inc, rng, 30)):
+        got = _host_columns(inc)
+        assert got.dtype == np.float32, i
+        np.testing.assert_array_equal(got, inc.distances, err_msg=f"op{i}")
+    assert inc.stats["joins"] and inc.stats["leaves"]
+    assert inc.stats["relaxations"] > inc.stats["joins"]
+    if threshold == 100:
+        assert inc.pending_deletions and not inc.stats["rebuilds"]
+    else:
+        assert inc.stats["rebuilds"]
+
+
+@pytest.mark.parametrize("capacity,n_live,method,slots", _REBUILD_CASES)
+def test_column_equals_host_column_after_a_rebuild(capacity, n_live, method,
+                                                   slots):
+    from repro.core import batcheval
+    w, adj, alive = _sparse_state(n_live, capacity, seed=capacity + n_live)
+    with batcheval.eval_options(method=method):
+        inc = IncrementalDistances(w, adj, alive)
+        inc.leave(int(inc.live_ids()[-1]))
+        inc.rebuild()
+    np.testing.assert_array_equal(_host_columns(inc), inc.distances)
+
+
 # ---------------------------------------------------------------------------
 # traces + scenarios
 # ---------------------------------------------------------------------------
@@ -460,6 +497,20 @@ def test_host_copies_of_distances_counted_once_per_mutation():
                                           {})}
     assert not fns & {"incremental.relax", "incremental.join",
                       "incremental.tombstone"}
+
+
+def test_column_read_counted_and_copies_no_matrix():
+    from repro.obs import REGISTRY
+    w, adj, alive = _fresh_state(16, 24, seed=5)
+    inc = IncrementalDistances(w, adj, alive)
+    reads = REGISTRY.get("repro_dist_column_reads_total")
+    inc.join(16, [0, 1])                   # a mutation: no host copy yet
+    copies, r0, s0 = _host_copies(), reads.value, _span_count("dist.column")
+    inc.column(3)
+    assert (reads.value, _span_count("dist.column")) == (r0 + 1, s0 + 1)
+    inc.column(3)
+    assert reads.value == r0 + 2
+    assert _host_copies() == copies
 
 
 def test_rebuild_phases_in_spans_and_scoring_outside_them():

@@ -14,7 +14,7 @@ Covers the PR's satellites explicitly:
 * durations come from the monotonic clock — a wall-clock step cannot
   corrupt ``uptime_s``;
 * spans nest on the profiler's clock: in a CPU trace a route request's
-  ``repro.state.route`` encloses its ``repro.dist.to_host`` on one thread,
+  ``repro.state.route`` encloses its ``repro.dist.column`` on one thread,
   and importing ``repro.obs`` loads no jax.
 """
 from __future__ import annotations
@@ -289,7 +289,7 @@ def test_route_spans_nest_on_the_profiler_clock(tmp_path):
     try:
         c = ServiceClient(server.url)
         c.wait_ready(timeout=30)
-        c.post_events(_events(events=6))     # a mutation: the next read copies
+        c.post_events(_events(events=6))     # a mutation before the route
         nodes = c.adjacency()["nodes"]
         jax.profiler.start_trace(str(tmp_path))
         try:
@@ -310,7 +310,7 @@ def test_route_spans_nest_on_the_profiler_clock(tmp_path):
         nested += [(h, r, d)
                    for h in by.get("repro.http.route", [])
                    for r in by.get("repro.state.route", [])
-                   for d in by.get("repro.dist.to_host", [])
+                   for d in by.get("repro.dist.column", [])
                    if inside(r, h) and inside(d, r)]
     assert len(nested) == 1, _repro_events(str(tmp_path))
 

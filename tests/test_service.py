@@ -19,6 +19,7 @@ import subprocess
 import sys
 import threading
 import time
+from typing import Dict
 
 import numpy as np
 import pytest
@@ -190,6 +191,59 @@ def test_served_distances_are_lower_bounds_while_stale():
         if r["distance"] is not None and truth < float(INF) / 2:
             assert r["distance"] <= truth + 1e-4
     assert state.stats()["distances_are"] == "exact"
+
+
+def _route_from_host_matrix(state: ServiceState, src: int, dst: int) -> Dict:
+    """The route's answer built from the whole host copy of D, through the
+    same greedy router."""
+    from repro.routing import route_single_host
+    inc = state.engine.inc
+    D = inc.distances
+    d = float(D[src, dst])
+    walk, lat, hops, outcome = route_single_host(
+        inc.adj, D[:, dst], src, dst, policy="latency",
+        hop_budget=inc.n_live)
+    delivered = outcome == "delivered"
+    bound = "lower" if inc.pending_deletions else "exact"
+    return {"distance": d, "path": walk if delivered else None,
+            "hops": hops if delivered else None,
+            "stretch": (float(lat) / d if d > 0 else 1.0) if delivered
+            else None,
+            "bound": bound, "stale": bound == "lower"}
+
+
+def _stale_state() -> ServiceState:
+    """A flat state after joins and leaves, its deletions left pending."""
+    state = ServiceState.fresh(_world(), policy="dgro",
+                               rebuild_threshold=64, seed=0)
+    state.ingest(_trace(events=16).events)
+    assert state.engine.inc.pending_deletions > 0
+    return state
+
+
+def test_route_answers_equal_the_whole_matrix_answers():
+    state = _stale_state()
+    live = [int(u) for u in state.engine.inc.live_ids()]
+    pairs = [(a, b) for a in live[:5] for b in live[-5:]]
+    served = {p: state.route(*p) for p in pairs}
+    assert any(r["path"] is not None and r["hops"] for r in served.values())
+    for (a, b), r in served.items():
+        want = _route_from_host_matrix(state, a, b)
+        assert {k: r[k] for k in want} == want, (a, b)
+        assert r["stale"] and r["reachable"]
+
+
+def test_routes_after_a_mutation_copy_no_matrix():
+    from repro.obs import REGISTRY
+    state = _stale_state()
+    copies = REGISTRY.get("repro_dist_host_copies_total")
+    reads = REGISTRY.get("repro_dist_column_reads_total")
+    live = [int(u) for u in state.engine.inc.live_ids()]
+    state.ingest([Event(time=40_000.0, kind="leave", node=live[0])])
+    c0, r0 = copies.value, reads.value
+    for b in live[2:8]:
+        state.route(live[1], b)
+    assert (copies.value, reads.value) == (c0, r0 + 6)
 
 
 # ---------------------------------------------------------------------------

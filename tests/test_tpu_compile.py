@@ -13,6 +13,7 @@ and every test worker imports every test file.  All such compiles live in
 this one file so that the worker given it is the only one that loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,3 +85,18 @@ def test_live_slot_gather_and_scatter_compile_without_a_sort(one_chip):
                         [((1, m, m), jnp.float32), ((c,), jnp.int32)])]:
         text = _compiled_text(fn, one_chip, *shapes)
         assert " gather(" in text and " sort(" not in text
+
+
+def test_column_read_compiles_to_one_slice_of_d(one_chip):
+    """A route's read of ``D[:, j]`` at capacity 8192, ``j`` traced: one
+    dynamic slice of a (8192, 1) column, with no copy of the 256 MiB
+    matrix, and an output of the column alone."""
+    c = 8192
+    compiled = jax.jit(incremental._column).lower(
+        jax.ShapeDtypeStruct((c, c), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert f"f32[{c},1]" in text and "dynamic-slice(" in text
+    assert not re.search(rf"= f32\[{c},{c}\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert (mem.output_size_in_bytes, mem.temp_size_in_bytes) == (c * 4, 0)
